@@ -33,6 +33,7 @@ import json
 import pathlib
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -44,7 +45,9 @@ def main() -> None:
         os.environ["BENCH_FAST"] = "0"
 
     from repro.common import telemetry
+    from repro.common.compile_cache import use_compile_cache
 
+    use_compile_cache()
     telemetry.enable()
 
     from benchmarks import (
@@ -69,18 +72,23 @@ def main() -> None:
     }
     wanted = [w for w in args.only.split(",") if w] or list(suites)
     print("name,us_per_call,derived")
+    failed = []
     for name in wanted:
         t0 = time.time()
         try:
             suites[name]()
-        except Exception as e:  # keep the harness running
-            print(f"{name}/ERROR,0.0,{type(e).__name__}: {e}", flush=True)
+        except Exception:  # run the other suites, then exit non-zero
+            traceback.print_exc()
+            failed.append(name)
+            print(f"{name}/ERROR,0.0,see stderr", flush=True)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
 
     out = pathlib.Path(__file__).resolve().parent.parent / "BENCH_RESULTS.json"
     out.write_text(json.dumps(
         telemetry.snapshot(suites=wanted), indent=2) + "\n")
     print(f"# wrote {out}", file=sys.stderr)
+    if failed:
+        sys.exit(f"suites failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -4,6 +4,8 @@ with KV/SSM caches, for any of the 10 assigned architectures (reduced config).
     PYTHONPATH=src python examples/serve_lm.py --arch mamba2-2.7b
 """
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -16,7 +18,11 @@ def main():
     cmd = [sys.executable, "-m", "repro.launch.serve", "--arch", arch,
            "--batch", "4", "--prompt-len", "16", "--gen", "8"]
     print(" ".join(cmd))
-    subprocess.run(cmd, check=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"})
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p)
+    subprocess.run(cmd, check=True, env=env)
 
 
 if __name__ == "__main__":

@@ -8,6 +8,8 @@ and reports filtered Hit@k / MR / MRR — the paper's evaluation protocol 1.
 """
 
 import argparse
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -33,7 +35,11 @@ def main():
         "--eval-every", str(args.eval_every),
     ]
     print(" ".join(cmd))
-    subprocess.run(cmd, check=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"})
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p)
+    subprocess.run(cmd, check=True, env=env)
 
 
 if __name__ == "__main__":

@@ -1,232 +1,68 @@
-"""Version-portable JAX surface — the ONLY module allowed to touch JAX
-symbols that have drifted across releases.
+"""The JAX spellings that move between releases, in one module.
 
-The repo targets a two-version contract: the pinned jax 0.4.x (list-valued
-cost analysis, ``jax.experimental.shard_map``, no mesh axis types) and the
-current stable line (dict-valued cost analysis, ``jax.shard_map`` with
-``check_vma``, explicit-sharding mesh axis types, ``jax.set_mesh``). Every
-adaptive decision lives here, behind a stable call signature, so kernels,
-launchers and tests query capabilities instead of sniffing ``jax.__version__``
-or scattering ``hasattr`` checks.
+The repo runs on one installation: JAX 0.9.0 (jaxlib 0.9.0, libtpu 0.0.34).
+Mesh construction, the ambient mesh, ``shard_map``, ``axis_size`` and the
+cost-analysis result have each changed name or shape across recent
+releases; call sites import them from here, so a future upgrade is fixed in
+this file and nowhere else.
 
-Rule (enforced by tests/test_compat.py and CI grep): no version-sensitive
-JAX symbol outside this module. If a new JAX release breaks an API we use,
-the fix lands here and nowhere else.
+Rule (enforced by tests/test_compat.py and CI grep): no such JAX symbol
+outside this module.
 
-All dispatches resolve at call time through the module-level ``jax``
-reference, so tests can monkeypatch a fake "old" or "new" module shape and
-exercise both branches on one installed JAX.
+All functions resolve through the module-level ``jax`` reference, so tests
+can monkeypatch a fake module and check exactly what is called.
 """
 
 from __future__ import annotations
 
-import contextlib
-import importlib
-import inspect
-from typing import Any, Dict, Sequence, Tuple
+from typing import Any, Dict, Sequence
 
 import jax
 
 
-def _experimental(name: str):
-    """Resolve ``jax.experimental.<name>`` whether or not it is already
-    imported (the package lazy-loads submodules), honouring monkeypatched
-    fake modules that pre-populate the attribute."""
-    mod = getattr(getattr(jax, "experimental", None), name, None)
-    if mod is None:
-        mod = importlib.import_module(f"{jax.__name__}.experimental.{name}")
-    return mod
-
-
-def _accepts_kw(fn, kw: str):
-    """True/False when ``fn``'s signature answers whether it takes ``kw``;
-    None when introspection can't tell (builtins, ``**kwargs`` wrappers) —
-    callers then fall back to try/except. Probing the signature first keeps
-    the except branch from masking unrelated TypeErrors raised by ``fn``."""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return None
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
-        return None
-    return kw in params
-
-
 # --------------------------------------------------------------- capabilities
-def jax_version() -> Tuple[int, ...]:
-    """(major, minor, patch) of the running JAX, zeros on parse failure."""
-    parts = []
-    for tok in str(jax.__version__).split(".")[:3]:
-        digits = "".join(c for c in tok if c.isdigit())
-        parts.append(int(digits) if digits else 0)
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
-
-
-def has_explicit_sharding() -> bool:
-    """True when this JAX has the explicit-sharding mesh model (axis types
-    on meshes, ``jax.set_mesh``); False on the 0.4.x line."""
-    return getattr(getattr(jax, "sharding", None), "AxisType", None) is not None
-
-
 def backend() -> str:
     """Default platform: 'cpu' | 'gpu' | 'tpu'."""
     return jax.default_backend()
 
 
 def interpret_kernels() -> bool:
-    """Whether Pallas kernels must run in interpret mode (no TPU present).
+    """Whether Pallas kernels run in interpret mode: exactly when there is
+    no TPU. On a TPU backend every kernel is compiled by Mosaic.
 
-    This is the single CPU-fallback switch for every kernel wrapper in
-    repro.kernels — kernels ask the compat layer, never the backend directly.
+    This is the single switch for every kernel wrapper in repro.kernels —
+    kernels ask the compat layer, never the backend directly. Entry points
+    print the platform they run on (launch/train.py, chip_smoke.py), so a
+    CPU run is never mistaken for a chip run.
     """
     return backend() != "tpu"
 
 
-def has_scalar_prefetch() -> bool:
-    """Whether this JAX exposes the Pallas scalar-prefetch grid spec
-    (``PrefetchScalarGridSpec``) that the sparse-Adagrad kernels rely on.
-
-    The symbol has lived in ``jax.experimental.pallas.tpu`` across the whole
-    supported range but is on a deprecation path; probing here keeps the
-    kernel wrappers version-agnostic (they fall back to jnp when absent).
-    """
-    try:
-        pltpu = importlib.import_module(
-            f"{jax.__name__}.experimental.pallas.tpu")
-    except ImportError:
-        return False
-    return getattr(pltpu, "PrefetchScalarGridSpec", None) is not None
-
-
-def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid,
-                              in_specs, out_specs):
-    """Build a Pallas grid spec whose first ``num_scalar_prefetch`` operands
-    are scalar-prefetched (available to ``index_map`` and the kernel body
-    before the block pipeline runs) — the only version-sensitive Pallas
-    spelling the sparse-Adagrad kernels need, pinned here per the compat rule.
-    """
-    pltpu = importlib.import_module(f"{jax.__name__}.experimental.pallas.tpu")
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:
-        raise NotImplementedError(
-            "this JAX has no Pallas scalar-prefetch grid spec; run with the "
-            "jnp sparse-Adagrad path (use_kernel=False)")
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-               in_specs=in_specs, out_specs=out_specs)
-
-
 # --------------------------------------------------------------------- meshes
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Build a device mesh portably.
-
-    Newest JAX wants axis types spelled out at construction; the 0.4.x
-    ``jax.make_mesh`` has no such keyword; releases before that have no
-    ``jax.make_mesh`` at all and go through ``mesh_utils``.
-    """
-    shape = tuple(int(s) for s in shape)
+    """A device mesh whose axes all have the ``Auto`` sharding type (the
+    behaviour every program here is written for)."""
     axes = tuple(axes)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        axis_type = getattr(getattr(jax, "sharding", None), "AxisType", None)
-        if axis_type is not None:
-            try:
-                return mk(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-            except TypeError:
-                pass  # AxisType exists but make_mesh predates the keyword
-        return mk(shape, axes)
-    mesh_utils = _experimental("mesh_utils")
-    devices = mesh_utils.create_device_mesh(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(tuple(int(s) for s in shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-@contextlib.contextmanager
 def set_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh, whatever this JAX calls that.
-
-    Newer releases: ``jax.set_mesh`` / ``jax.sharding.use_mesh`` context
-    managers. 0.4.x: the Mesh object itself is the context manager.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is None:
-        setter = getattr(getattr(jax, "sharding", None), "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-        return
-    with mesh:
-        yield mesh
+    """Context manager entering ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 # ------------------------------------------------------------------ shard_map
 def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` across its two homes and replication-check spellings.
-
-    Newer JAX: ``jax.shard_map(..., check_vma=...)``. 0.4.x:
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        sm = _experimental("shard_map").shard_map
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    takes_vma = _accepts_kw(sm, "check_vma")
-    if takes_vma:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    if takes_vma is False:
-        # transitional releases exposed jax.shard_map with check_rep
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    try:  # uninspectable signature: probe by calling
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
-
-# ------------------------------------------------------------- jit / sharding
-def jit(fun, **kwargs):
-    """``jax.jit`` tolerant of donation-keyword drift.
-
-    Donation is a memory optimization, never a semantic requirement: if this
-    JAX rejects the donation keywords we were given, drop them rather than
-    fail the program.
-    """
-    donation = ("donate_argnums", "donate_argnames")
-    for kw in donation:
-        if kw in kwargs and _accepts_kw(jax.jit, kw) is False:
-            kwargs.pop(kw)
-    try:
-        return jax.jit(fun, **kwargs)
-    except TypeError:
-        if not any(kw in kwargs for kw in donation):
-            raise
-        kwargs = {k: v for k, v in kwargs.items() if k not in donation}
-        return jax.jit(fun, **kwargs)
-
-
-def with_sharding_constraint(x, shardings):
-    """``with_sharding_constraint`` across its lax / pjit homes."""
-    wsc = getattr(jax.lax, "with_sharding_constraint", None)
-    if wsc is None:
-        wsc = _experimental("pjit").with_sharding_constraint
-    return wsc(x, shardings)
+    """``jax.shard_map`` with its replication check spelled ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 # ---------------------------------------------------------------- collectives
 def axis_size(axis_name):
-    """Size of a named mesh axis (or tuple of axes) inside a mapped body.
-
-    ``jax.lax.axis_size`` postdates the 0.4.x line; there the idiom is a
-    psum of the constant 1 over the axis, which folds to a static int.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a named mesh axis (or tuple of axes) inside a mapped body."""
+    return jax.lax.axis_size(axis_name)
 
 
 def all_to_all(x, axis_name, split_axis: int, concat_axis: int, *,
@@ -239,26 +75,6 @@ def all_to_all(x, axis_name, split_axis: int, concat_axis: int, *,
 
 # -------------------------------------------------------------- cost analysis
 def cost_analysis(compiled) -> Dict[str, Any]:
-    """Normalized XLA cost analysis of a ``Compiled``: always one flat dict.
-
-    jax 0.4.x returns a list with one dict per program; newer releases return
-    the dict directly (or None for backends without an implementation).
-    Numeric values repeated across programs are summed; everything else keeps
-    its first occurrence.
-    """
-    raw = compiled.cost_analysis()
-    if raw is None:
-        return {}
-    if isinstance(raw, dict):
-        return dict(raw)
-    out: Dict[str, Any] = {}
-    for entry in raw:
-        if not isinstance(entry, dict):
-            continue
-        for k, v in entry.items():
-            if k in out and isinstance(v, (int, float)) \
-                    and isinstance(out[k], (int, float)):
-                out[k] += v
-            elif k not in out:
-                out[k] = v
-    return out
+    """XLA cost analysis of a ``Compiled`` as a flat dict ({} for backends
+    without an implementation, where JAX returns None)."""
+    return dict(compiled.cost_analysis() or {})

@@ -1,8 +1,12 @@
-"""Target-hardware constants used by the roofline analysis.
+"""Published peaks of the chips this repo runs on, keyed by ``device_kind``.
 
-The runtime container is CPU-only; TPU v5e is the *target*. All roofline
-terms in benchmarks/ and launch/dryrun.py are derived from these constants
-plus the compiled HLO of the dry-run (never from CPU wall-clock).
+Look a device up with ``hw_for(jax.devices()[0].device_kind)``. A kind that
+is not in ``PEAKS`` is an error, never a default: a roofline share against
+another chip's peaks is a wrong number, not an approximate one.
+
+Source for TPU v5e: Google Cloud documentation, "TPU v5e" (system
+architecture table): 197 TFLOP/s bf16, 16 GB HBM2 at 819 GB/s, 1,600 Gbit/s
+of chip-to-chip interconnect (four ICI links), 128 MiB of VMEM per core.
 """
 
 from dataclasses import dataclass
@@ -28,3 +32,20 @@ TPU_V5E = HwSpec(
     vmem_bytes=128 * 1024**2,
     mxu_dim=128,
 )
+
+# jax.devices()[0].device_kind -> peaks. JAX reports a v5e chip as
+# "TPU v5 lite"; "TPU v5e" is the marketing name some releases use.
+PEAKS = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def hw_for(device_kind: str) -> HwSpec:
+    """Peaks of ``device_kind``; ValueError for a kind not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            "to common/hw.py PEAKS with their source") from None

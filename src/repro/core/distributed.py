@@ -457,7 +457,7 @@ def build_dist_train_step(prog: DistKGEProgram, mesh: Mesh, pairwise_fn=None):
     )
     state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs,
                             is_leaf=lambda x: isinstance(x, P))
-    return compat.jit(smapped, donate_argnums=(0,)), state_sh, jax.tree.map(
+    return jax.jit(smapped, donate_argnums=(0,)), state_sh, jax.tree.map(
         lambda s: NamedSharding(mesh, s), batch_specs,
         is_leaf=lambda x: isinstance(x, P))
 
@@ -559,7 +559,7 @@ def build_pipelined_dist_step(prog: DistKGEProgram, mesh: Mesh,
             functools.partial(_device_prime, prog, maxis), mesh=mesh,
             in_specs=(state_specs, batch_specs),
             out_specs=state_specs, check_vma=False)
-        prime_fn = compat.jit(prime, donate_argnums=(0,))
+        prime_fn = jax.jit(prime, donate_argnums=(0,))
     else:
         body = functools.partial(_device_step, prog, maxis,
                                  pairwise_fn=pairwise_fn, n_servers=n_srv)
@@ -567,14 +567,14 @@ def build_pipelined_dist_step(prog: DistKGEProgram, mesh: Mesh,
             body, mesh=mesh,
             in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, metric_specs), check_vma=False)
-    step_fn = compat.jit(smapped, donate_argnums=(0,))
+    step_fn = jax.jit(smapped, donate_argnums=(0,))
 
     flush_fn = None
     if prog.push_every > 1:
         fmapped = compat.shard_map(
             functools.partial(_device_push_flush, prog, maxis), mesh=mesh,
             in_specs=(state_specs,), out_specs=state_specs, check_vma=False)
-        flush_fn = compat.jit(fmapped, donate_argnums=(0,))
+        flush_fn = jax.jit(fmapped, donate_argnums=(0,))
 
     runner = PipelinedDistStep(step_fn, prime_fn, flush_fn,
                                push_every=prog.push_every,

@@ -99,8 +99,8 @@ def _adagrad_rows(table, gsq, ids, grads, lr):
     """Aggregate duplicate ids, then sparse-Adagrad the touched rows.
 
     Delegates to ``optim.sparse_adagrad_apply``, which dispatches between the
-    jnp path and the fused Pallas kernel per its auto-probed ``use_kernel``
-    flag — stores and trainers never choose a path themselves.
+    jnp path and the fused Pallas kernel per its platform-default
+    ``use_kernel`` flag — stores and trainers never choose a path themselves.
     """
     return sparse_adagrad_apply(table, gsq, ids, grads, lr)
 

@@ -99,7 +99,9 @@ def _l1_do_kernel(o_ref, n_ref, g_ref, out_ref):
     n = n_ref[...].astype(jnp.float32)  # (bn, bk)
     g = g_ref[...].astype(jnp.float32)  # (bm, bn)
     s = jnp.sign(o[:, None, :] - n[None, :, :])  # (bm, bn, bk)
-    out_ref[...] += jnp.einsum("mn,mnd->md", g, s)
+    # a broadcast-multiply-reduce on the VPU: Mosaic has no batched 3-D
+    # dot_general, which is what an einsum over (m, n, d) would lower to
+    out_ref[...] += jnp.sum(g[:, :, None] * s, axis=1)
 
 
 def _l1_dn_kernel(o_ref, n_ref, g_ref, out_ref):
@@ -113,7 +115,7 @@ def _l1_dn_kernel(o_ref, n_ref, g_ref, out_ref):
     n = n_ref[...].astype(jnp.float32)  # (bn, bk)
     g = g_ref[...].astype(jnp.float32)  # (bm, bn)
     s = jnp.sign(o[:, None, :] - n[None, :, :])  # (bm, bn, bk)
-    out_ref[...] += -jnp.einsum("mn,mnd->nd", g, s)
+    out_ref[...] -= jnp.sum(g[:, :, None] * s, axis=0)
 
 
 def l1_bwd_pallas(o, negs, g, *, bm=128, bn=128, bk=128, interpret=False):

@@ -7,11 +7,11 @@ argsort/segment_sum dedup itself. optim/sparse_adagrad.py routes through
 these behind its ``use_kernel`` flag — nothing else should call them.
 
 Contracts:
-  * ``fused_sparse_adagrad``: valid ids must be UNIQUE (duplicate rows would
-    race the block pipeline — see the hazard note in sparse_adagrad.py and
-    optim/sparse_adagrad.py). Pad slots (id < 0) may appear anywhere; they
-    are exact no-ops. The table's D axis is never padded or copied — the
-    kernel updates the aliased buffers in place.
+  * ``fused_sparse_adagrad``: valid ids must be UNIQUE (of a duplicated
+    row only one gradient would land — see optim/sparse_adagrad.py). Pad slots
+    (id < 0) may appear anywhere; they are exact no-ops. The table is never
+    padded or copied — the kernel updates the aliased buffers in place, one
+    memory tile of ``tr`` rows at a time (``_group_tiles``).
   * ``dedup_aggregate``: any ids (duplicates + pads); returns the in-place
     layout of ref.dedup_aggregate_ref.
 """
@@ -40,12 +40,12 @@ def _row_tile(D: int) -> int:
 
 
 def _pad_remap(ids: jnp.ndarray) -> jnp.ndarray:
-    """Remap pad slots to the nearest *preceding* valid slot's row id.
+    """Remap pad slots to the nearest *preceding* valid slot's id.
 
     This makes every pad step a consecutive revisit of an already-resident
     block (no refetch — the Pallas pipeline only moves blocks when the index
     map output changes), which is what makes pads hazard-free. Leading pads
-    map to the first valid id; an all-pad batch maps to row 0 (the kernel
+    map to the first valid id; an all-pad batch maps to block 0 (the kernel
     then performs a bitwise no-op copy at step 0 only).
     """
     n = ids.shape[0]
@@ -56,6 +56,39 @@ def _pad_remap(ids: jnp.ndarray) -> jnp.ndarray:
     rmap = jnp.where(last_valid >= 0, ids[jnp.maximum(last_valid, 0)],
                      ids[first_valid])
     return jnp.maximum(rmap, 0).astype(jnp.int32)
+
+
+def _tile_rows(dtype) -> int:
+    """Rows in one (8, 128) memory tile of the TPU: 8 for 32-bit rows, 16
+    for 16-bit — the smallest row block Mosaic can DMA or window."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _group_tiles(ids: jnp.ndarray, grads: jnp.ndarray, tr: int,
+                 n_tiles: int):
+    """Group unique row ids by the ``tr``-row tile that holds them.
+
+    Returns ``(tile_ids (n_tiles,), grad_tiles (n_tiles*tr, D),
+    mask (n_tiles*tr, 1))``: the distinct touched tiles, ascending and
+    compacted with -1 pads, and row ``r`` of slot ``s`` at ``s*tr + r``
+    holding that row's gradient (mask 1) or nothing (mask 0). Distinct
+    rows give distinct (slot, row) pairs, so the scatter never collides.
+    """
+    n, D = grads.shape
+    valid = ids >= 0
+    tile = jnp.where(valid, ids // tr, jnp.iinfo(jnp.int32).max)
+    order = jnp.argsort(tile)
+    st, sv, sid = tile[order], valid[order], ids[order]
+    first = sv & jnp.concatenate([jnp.ones((1,), bool), st[1:] != st[:-1]])
+    slot = jnp.cumsum(first) - 1
+    tile_ids = jnp.full((n_tiles,), -1, jnp.int32).at[
+        jnp.where(first, slot, n_tiles)].set(st, mode="drop")
+    dest = jnp.where(sv, slot * tr + sid % tr, n_tiles * tr)
+    grad_tiles = jnp.zeros((n_tiles * tr, D), grads.dtype).at[dest].set(
+        grads[order], mode="drop")
+    mask = jnp.zeros((n_tiles * tr, 1), jnp.int32).at[dest].set(
+        1, mode="drop")
+    return tile_ids, grad_tiles, mask
 
 
 def fused_sparse_adagrad(
@@ -71,10 +104,12 @@ def fused_sparse_adagrad(
     if ids.shape[0] == 0:
         return table, gsq
     interpret = _interpret() if interpret is None else interpret
-    ids = ids.astype(jnp.int32)
-    grads = grads.astype(table.dtype)
+    tr = _tile_rows(table.dtype)
+    n_tiles = min(ids.shape[0], -(-table.shape[0] // tr))
+    tile_ids, grad_tiles, mask = _group_tiles(
+        ids.astype(jnp.int32), grads.astype(table.dtype), tr, n_tiles)
     return fused_update_pallas(
-        table, gsq, _pad_remap(ids), ids, grads,
+        table, gsq, _pad_remap(tile_ids), tile_ids, grad_tiles, mask,
         lr=lr, eps=eps, bd=_row_tile(table.shape[1]), interpret=interpret)
 
 

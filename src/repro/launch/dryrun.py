@@ -24,9 +24,13 @@ import jax
 
 from repro.common import compat
 from repro.common.config import INPUT_SHAPES
+from repro.common.hw import hw_for
 from repro.launch.hlo_analysis import analyze_hlo, xla_cost_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import format_row, roofline_from_compiled
+
+# the production mesh the dry-run compiles for is a TPU v5e pod
+TARGET_HW = hw_for("TPU v5 lite")
 
 
 def dryrun_arch(arch_name: str, shape_name: str, multi_pod: bool,
@@ -61,7 +65,7 @@ def dryrun_arch(arch_name: str, shape_name: str, multi_pod: bool,
         if shape.kind == "train":
             step, _ = build_train_step(model, shape=shape)
             aps, aos, batch = train_abstract_args(model, shape)
-            lowered = compat.jit(step, donate_argnums=(0, 1)).lower(aps, aos, batch)
+            lowered = jax.jit(step, donate_argnums=(0, 1)).lower(aps, aos, batch)
         elif shape.kind == "prefill":
             step = build_prefill_step(model, use_flash=use_flash)
             aps = jax.tree.map(
@@ -75,7 +79,7 @@ def dryrun_arch(arch_name: str, shape_name: str, multi_pod: bool,
         else:  # decode
             step = build_serve_step(model)
             aps, caches, token, index = serve_abstract_args(model, shape)
-            lowered = compat.jit(step, donate_argnums=(1,)).lower(
+            lowered = jax.jit(step, donate_argnums=(1,)).lower(
                 aps, caches, token, index)
         t_lower = time.time() - t0
         t0 = time.time()
@@ -89,7 +93,7 @@ def dryrun_arch(arch_name: str, shape_name: str, multi_pod: bool,
     cost = analyze_hlo(txt, total_devices=chips)
     rl = roofline_from_compiled(
         compiled, arch_name, shape_name, mesh_name, chips,
-        model_flops=cfg.model_flops(shape), hlo_cost=cost)
+        model_flops=cfg.model_flops(shape), hw=TARGET_HW, hlo_cost=cost)
     row = rl.row()
     row.update(lower_s=t_lower, compile_s=t_compile)
     try:
@@ -167,7 +171,8 @@ def dryrun_kge(dataset: str, multi_pod: bool, model: str = "",
     mf = P_ * (2 * 2.0 * b * k * d + 3 * 2.0 * b * d) * 3  # fwd+bwd(2x)
     rl = roofline_from_compiled(
         compiled, f"kge-{dataset}-{cfg.model}", "kge_step",
-        "x".join(str(s) for s in mesh.devices.shape), chips, mf, hlo_cost=cost)
+        "x".join(str(s) for s in mesh.devices.shape), chips, mf,
+        hw=TARGET_HW, hlo_cost=cost)
     row = rl.row()
     row["compile_s"] = t_compile
     ma = compiled.memory_analysis()

@@ -5,7 +5,8 @@
     collective = collective_bytes / (chips × link_bw)
 
 FLOPs / bytes / collective bytes come from launch/hlo_analysis.py (per-device,
-while-loop aware). Hardware constants: common/hw.py (TPU v5e).
+while-loop aware). Hardware peaks: common/hw.py, looked up by device kind —
+the caller names the chip; there is no default.
 """
 
 from __future__ import annotations
@@ -13,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
-from repro.common.hw import TPU_V5E, HwSpec
+from repro.common.hw import HwSpec
 from repro.launch.hlo_analysis import HloCost, analyze_hlo
 
 
@@ -78,7 +79,8 @@ def roofline_from_compiled(
     mesh_name: str,
     chips: int,
     model_flops: float,
-    hw: HwSpec = TPU_V5E,
+    *,
+    hw: HwSpec,
     hlo_cost: Optional[HloCost] = None,
 ) -> Roofline:
     cost = hlo_cost or analyze_hlo(compiled.as_text(), total_devices=chips)

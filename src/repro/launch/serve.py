@@ -37,11 +37,12 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from repro.common import compat
+    from repro.common.compile_cache import use_compile_cache
     from repro.configs import get_arch
     from repro.launch.engine import TelemetryHook, ThroughputHook, run_loop
     from repro.models.transformer import build_model
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = cfg.reduced()
@@ -57,7 +58,7 @@ def main():
         model.cache_defs(B, total),
         is_leaf=lambda x: hasattr(x, "materialize"))
 
-    decode = compat.jit(model.decode_step, donate_argnums=(1,))
+    decode = jax.jit(model.decode_step, donate_argnums=(1,))
 
     # prefill + generate through the shared engine loop (prefill_step exists
     # for the batch path; the serving loop here feeds the prompt token by

@@ -8,10 +8,10 @@ Hogwild multi-trainer / multi-sampler (paper §3.1/§3.3, launch/runtime.py):
     PYTHONPATH=src python -m repro.launch.train --dataset fb15k \
         --trainers 4 --samplers 4 --steps 2000
 
-Distributed mode (SPMD over a CPU mesh here; the same program runs on the
-production mesh):
+Distributed mode (one KVStore machine per device by default — ``--mesh
+4x1`` on a four-chip v5e host; ``machines x servers`` in general):
     PYTHONPATH=src python -m repro.launch.train --dataset fb15k --distributed \
-        --mesh 4x2 --steps 500 --partitioner metis
+        --mesh 4x1 --steps 500 --partitioner metis
 
 All of the paper's techniques are switchable:
     --neg-mode joint|naive        (T1)
@@ -39,6 +39,8 @@ in embeddings/store.py).
 
 Both modes run through launch/engine.train_loop — the mode only decides the
 step function, the sampler, and the store backend (see core/step.py).
+``main(argv, hooks)`` returns the final training state, and ``hooks`` are
+appended to the loop's own (chip_smoke.py drives the trainer this way).
 """
 
 from __future__ import annotations
@@ -47,7 +49,7 @@ import argparse
 import dataclasses
 
 
-def main():
+def main(argv=None, hooks=()):
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="fb15k", choices=["fb15k", "wn18", "freebase"])
     ap.add_argument("--model", default="transe_l2")
@@ -77,7 +79,9 @@ def main():
                     help="distributed only: coalesce remote grad pushes in "
                          "per-peer merge buffers and flush them as one "
                          "deduplicated all_to_all every K steps")
-    ap.add_argument("--mesh", default="4x2", help="data x model, e.g. 4x2")
+    ap.add_argument("--mesh", default="",
+                    help="machines x servers, e.g. 4x1; default: one "
+                         "machine per device")
     ap.add_argument("--partitioner", default="metis", choices=["metis", "random"])
     ap.add_argument("--no-overlap", action="store_true")
     ap.add_argument("--use-kernel", action="store_true")
@@ -93,7 +97,16 @@ def main():
     ap.add_argument("--save-every", type=int, default=0)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from repro.common.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    dev = jax.devices()
+    print(f"platform: {dev[0].platform} ({dev[0].device_kind}) "
+          f"x{len(dev)}")
 
     if args.metrics_out or args.trace_out:
         from repro.common import telemetry
@@ -148,12 +161,11 @@ def main():
                  "(they pipeline the KVStore collectives)")
 
     if args.distributed:
-        _train_distributed(args, cfg, kg, pairwise_fn)
-    else:
-        _train_single(args, cfg, kg, pairwise_fn)
+        return _train_distributed(args, cfg, kg, pairwise_fn, hooks)
+    return _train_single(args, cfg, kg, pairwise_fn, hooks)
 
 
-def _train_single(args, cfg, kg, pairwise_fn):
+def _train_single(args, cfg, kg, pairwise_fn, extra_hooks):
     import functools
 
     import jax
@@ -237,14 +249,15 @@ def _train_single(args, cfg, kg, pairwise_fn):
 
     if args.eval or args.eval_every:
         hooks.append(EvalHook(evaluate, eval_every=args.eval_every))
+    hooks.extend(extra_hooks)
 
-    train_loop(step, state, lambda: (to_dev(sampler.sample()), None),
-               args.steps, start=start, hooks=hooks,
-               n_trainers=args.trainers, n_samplers=args.samplers,
-               sampler_factory=sampler_factory, split_step=split_step)
+    return train_loop(step, state, lambda: (to_dev(sampler.sample()), None),
+                      args.steps, start=start, hooks=hooks,
+                      n_trainers=args.trainers, n_samplers=args.samplers,
+                      sampler_factory=sampler_factory, split_step=split_step)
 
 
-def _train_distributed(args, cfg, kg, pairwise_fn):
+def _train_distributed(args, cfg, kg, pairwise_fn, extra_hooks):
     import numpy as np
 
     import jax
@@ -265,7 +278,8 @@ def _train_distributed(args, cfg, kg, pairwise_fn):
     )
     from repro.launch.mesh import make_mesh
 
-    dshape = tuple(int(x) for x in args.mesh.split("x"))
+    mesh_arg = args.mesh or f"{jax.device_count()}x1"
+    dshape = tuple(int(x) for x in mesh_arg.split("x"))
     names = ("data", "model") if len(dshape) == 2 else ("pod", "data", "model")
     mesh = make_mesh(dshape, names)
     n_parts = int(np.prod(dshape[:-1]))
@@ -331,11 +345,13 @@ def _train_distributed(args, cfg, kg, pairwise_fn):
                                        every=max(1, args.log_every)))
         if args.ckpt_dir:
             hooks.append(CheckpointHook(args.ckpt_dir, args.save_every))
-        train_loop(step, state, batch_fn(sampler), args.steps, start=start,
-                   hooks=hooks, n_trainers=args.trainers,
-                   n_samplers=args.samplers,
-                   sampler_factory=lambda wid: batch_fn(samplers[wid]))
+        hooks.extend(extra_hooks)
+        state = train_loop(step, state, batch_fn(sampler), args.steps,
+                           start=start, hooks=hooks, n_trainers=args.trainers,
+                           n_samplers=args.samplers,
+                           sampler_factory=lambda wid: batch_fn(samplers[wid]))
     print("done")
+    return state
 
 
 if __name__ == "__main__":

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.common import compat
 from repro.common.config import ArchConfig, InputShape
 from repro.models.layers import ParamDef, abstract, is_def, specs
 from repro.models.transformer import Model
@@ -136,7 +135,7 @@ def build_train_step(model: Model, lr: float = 1e-4, shape: Optional[InputShape]
         if model.mesh is None:
             return g
         return jax.tree.map(
-            lambda x, sp: compat.with_sharding_constraint(
+            lambda x, sp: jax.lax.with_sharding_constraint(
                 x, NamedSharding(model.mesh, sp)),
             g, model.param_specs(),
             is_leaf=lambda x: isinstance(x, P) or hasattr(x, "dtype"))

@@ -11,15 +11,14 @@ Two implementations sit behind one entry point, ``sparse_adagrad_apply``
 * the **jnp path** — argsort + ``segment_sum`` dedup followed by scatter-add
   row updates (≈4 HBM passes over the touched rows per table per step);
 * the **fused Pallas path** (kernels/sparse_adagrad) — a tiled on-device
-  dedup-aggregate plus ONE pass per touched row that reads the aggregated
-  gradient, bumps ``gsq``, computes the step from the *updated* accumulator
-  (the DGL-KE order) and writes the row back, with ``table`` and ``gsq``
-  aliased in place.
+  dedup-aggregate plus ONE pass per touched 8-row memory tile that reads the
+  aggregated gradients, bumps ``gsq``, computes the step from the *updated*
+  accumulator (the DGL-KE order) and writes the tile back, with ``table``
+  and ``gsq`` aliased in place.
 
-Which path runs is the ``use_kernel`` flag: ``None`` (default) auto-probes —
-kernels on a TPU backend with scalar-prefetch Pallas, jnp otherwise —
-overridable per-process with ``set_use_kernel`` or the
-``REPRO_SPARSE_ADAGRAD_KERNEL`` env var (0/1). The flag is read at *trace*
+Which path runs is the ``use_kernel`` flag: ``None`` (default) means the
+kernels on a TPU backend and jnp otherwise — overridable per-process with
+``set_use_kernel`` or the ``REPRO_SPARSE_ADAGRAD_KERNEL`` env var (0/1). The flag is read at *trace*
 time: already-jitted step functions keep the path they were traced with.
 
 Padding convention: ids equal to ``pad_id`` (< 0 after masking, remapped to
@@ -53,7 +52,8 @@ _USE_KERNEL_OVERRIDE: Optional[bool] = None
 
 
 def set_use_kernel(flag: Optional[bool]) -> None:
-    """Force (True/False) or restore auto-probing (None) of the fused kernel.
+    """Force (True/False) or restore the platform default (None) of the
+    fused kernel.
 
     Takes effect at the next trace — functions already jitted keep the path
     they were traced with (build step functions after flipping the flag).
@@ -63,13 +63,13 @@ def set_use_kernel(flag: Optional[bool]) -> None:
 
 
 def use_kernel() -> bool:
-    """Resolve the auto-probed kernel flag (see module docstring)."""
+    """Resolve the kernel flag (see module docstring)."""
     if _USE_KERNEL_OVERRIDE is not None:
         return _USE_KERNEL_OVERRIDE
     env = os.environ.get("REPRO_SPARSE_ADAGRAD_KERNEL")
     if env is not None:
         return env.lower() not in ("0", "false", "")
-    return compat.backend() == "tpu" and compat.has_scalar_prefetch()
+    return compat.backend() == "tpu"
 
 
 def _resolve(flag: Optional[bool]) -> bool:

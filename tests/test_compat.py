@@ -1,9 +1,8 @@
-"""The version-portable JAX surface (repro/common/compat.py).
+"""The JAX spellings that move between releases (repro/common/compat.py).
 
-The compat layer is the repo's two-version contract: every function must
-behave identically through the "old" (jax 0.4.x) and "new" (current stable)
-API shapes. Both shapes are exercised here via monkeypatched fake jax
-modules, plus an integration pass against whichever real JAX is installed.
+Every function is checked against a monkeypatched fake jax module of the
+installed shape (exactly what compat calls), plus an integration pass
+against the real installed JAX.
 """
 
 import enum
@@ -60,58 +59,34 @@ class _CtxRecorder:
 
 
 def fake_new_jax():
-    """Current-stable shape: AxisType, make_mesh(axis_types=), jax.shard_map
-    with check_vma, jax.set_mesh."""
+    """The installed shape: AxisType, make_mesh(axis_types=), jax.shard_map
+    with check_vma, jax.set_mesh, jax.lax.axis_size."""
     jx = types.SimpleNamespace()
-    jx.__version__ = "0.7.2"
-    jx.__name__ = "fake_new_jax"
+    jx.__version__ = "0.9.0"
     jx.sharding = types.SimpleNamespace(AxisType=_AxisType)
     jx.make_mesh = _Recorder(result="new-mesh")
     jx.shard_map = _Recorder(result="new-mapped", reject=("check_rep",))
     jx.set_mesh = _CtxRecorder()
-    jx.jit = _Recorder(result="new-jitted")
-    jx.lax = types.SimpleNamespace(
-        with_sharding_constraint=_Recorder(result="new-constrained"))
+    jx.lax = types.SimpleNamespace(axis_size=_Recorder(result=4))
     jx.default_backend = lambda: "tpu"
     return jx
 
 
-def fake_old_jax():
-    """0.4.x shape: no AxisType, make_mesh without axis_types, shard_map in
-    jax.experimental with check_rep, no set_mesh (Mesh is the context)."""
-    jx = types.SimpleNamespace()
-    jx.__version__ = "0.4.37"
-    jx.__name__ = "fake_old_jax"
-    jx.sharding = types.SimpleNamespace()  # no AxisType, no use_mesh
-    jx.make_mesh = _Recorder(result="old-mesh", reject=("axis_types",))
-    jx.experimental = types.SimpleNamespace(
-        shard_map=types.SimpleNamespace(
-            shard_map=_Recorder(result="old-mapped", reject=("check_vma",))))
-    jx.jit = _Recorder(result="old-jitted", reject=("donate_argnums",))
-    jx.lax = types.SimpleNamespace(
-        with_sharding_constraint=_Recorder(result="old-constrained"))
-    jx.default_backend = lambda: "cpu"
-    return jx
-
-
-@pytest.fixture(params=["old", "new"])
+@pytest.fixture(params=["new"])
 def fake(request, monkeypatch):
-    jx = fake_old_jax() if request.param == "old" else fake_new_jax()
+    jx = fake_new_jax()
     monkeypatch.setattr(compat, "jax", jx)
     return request.param, jx
 
 
-# ---------------------------------------------------------------- both shapes
+# ------------------------------------------------------------ installed shape
 def test_make_mesh_both_shapes(fake):
     kind, jx = fake
     mesh = compat.make_mesh((4, 2), ("data", "model"))
     assert mesh == f"{kind}-mesh"
     (args, kwargs), = jx.make_mesh.calls
     assert args == ((4, 2), ("data", "model"))
-    if kind == "new":
-        assert kwargs == {"axis_types": (_AxisType.Auto, _AxisType.Auto)}
-    else:
-        assert kwargs == {}
+    assert kwargs == {"axis_types": (_AxisType.Auto, _AxisType.Auto)}
 
 
 def test_shard_map_both_shapes(fake):
@@ -123,53 +98,23 @@ def test_shard_map_both_shapes(fake):
     out = compat.shard_map(body, mesh="m", in_specs="i", out_specs="o",
                            check_vma=False)
     assert out == f"{kind}-mapped"
-    rec = jx.shard_map if kind == "new" else jx.experimental.shard_map.shard_map
-    (args, kwargs), = rec.calls
+    (args, kwargs), = jx.shard_map.calls
     assert args == (body,)
     assert kwargs["mesh"] == "m"
     assert kwargs["in_specs"] == "i" and kwargs["out_specs"] == "o"
-    flag = "check_vma" if kind == "new" else "check_rep"
-    assert kwargs[flag] is False
+    assert kwargs["check_vma"] is False
 
 
 def test_capability_probe_both_shapes(fake):
-    kind, _ = fake
-    assert compat.has_explicit_sharding() == (kind == "new")
-    assert compat.backend() == ("tpu" if kind == "new" else "cpu")
+    kind, jx = fake
+    assert compat.backend() == "tpu"
     # kernels interpret exactly when there is no TPU
-    assert compat.interpret_kernels() == (kind != "new")
-    assert compat.jax_version() == ((0, 7, 2) if kind == "new" else (0, 4, 37))
-
-
-def test_jit_donation_both_shapes(fake):
-    kind, jx = fake
-    out = compat.jit(abs, donate_argnums=(0,), static_argnames=("k",))
-    assert out == f"{kind}-jitted"
-    (args, kwargs), = jx.jit.calls
-    assert args == (abs,)
-    assert kwargs.get("static_argnames") == ("k",)
-    if kind == "new":
-        assert kwargs.get("donate_argnums") == (0,)
-    else:  # donation keyword rejected -> retried without it
-        assert "donate_argnums" not in kwargs
-
-
-def test_with_sharding_constraint_both_shapes(fake):
-    kind, jx = fake
-    assert compat.with_sharding_constraint("x", "s") == f"{kind}-constrained"
-    (args, _), = jx.lax.with_sharding_constraint.calls
-    assert args == ("x", "s")
-
-
-# --------------------------------------------------- transitional make_mesh
-def test_make_mesh_axis_type_without_keyword(monkeypatch):
-    """AxisType exists but make_mesh predates the axis_types keyword."""
-    jx = fake_new_jax()
-    jx.make_mesh = _Recorder(result="mid-mesh", reject=("axis_types",))
-    monkeypatch.setattr(compat, "jax", jx)
-    assert compat.make_mesh((2,), ("data",)) == "mid-mesh"
-    (args, kwargs), = jx.make_mesh.calls
-    assert args == ((2,), ("data",)) and kwargs == {}
+    assert compat.interpret_kernels() is False
+    jx.default_backend = lambda: "cpu"
+    assert compat.interpret_kernels() is True
+    assert compat.axis_size("data") == 4
+    (args, _), = jx.lax.axis_size.calls
+    assert args == ("data",)
 
 
 def test_set_mesh_new_uses_setter(monkeypatch):
@@ -181,25 +126,6 @@ def test_set_mesh_new_uses_setter(monkeypatch):
     assert jx.set_mesh.exited == ["the-mesh"]
 
 
-def test_set_mesh_old_enters_mesh(monkeypatch):
-    jx = fake_old_jax()
-    monkeypatch.setattr(compat, "jax", jx)
-    log = []
-
-    class Mesh:
-        def __enter__(self):
-            log.append("enter")
-            return self
-
-        def __exit__(self, *exc):
-            log.append("exit")
-            return False
-
-    with compat.set_mesh(Mesh()):
-        assert log == ["enter"]
-    assert log == ["enter", "exit"]
-
-
 # -------------------------------------------------------------- cost analysis
 class _Compiled:
     def __init__(self, raw):
@@ -209,26 +135,18 @@ class _Compiled:
         return self.raw
 
 
-@pytest.mark.parametrize("raw", [
-    [{"flops": 10.0, "bytes accessed": 5.0}],           # 0.4.x list shape
-    {"flops": 10.0, "bytes accessed": 5.0},             # new dict shape
+@pytest.mark.parametrize("raw,want", [
+    ({"flops": 10.0, "bytes accessed": 5.0},
+     {"flops": 10.0, "bytes accessed": 5.0}),
+    (None, {}),                                         # no implementation
 ])
-def test_cost_analysis_normalizes_both_shapes(raw):
-    assert compat.cost_analysis(_Compiled(raw)) == {
-        "flops": 10.0, "bytes accessed": 5.0}
-
-
-def test_cost_analysis_none_and_multi_program():
-    assert compat.cost_analysis(_Compiled(None)) == {}
-    multi = [{"flops": 10.0, "label": "a"}, {"flops": 2.5, "label": "b"}]
-    out = compat.cost_analysis(_Compiled(multi))
-    assert out["flops"] == 12.5        # numeric keys sum across programs
-    assert out["label"] == "a"         # non-numeric keep first occurrence
+def test_cost_analysis_normalizes_both_shapes(raw, want):
+    assert compat.cost_analysis(_Compiled(raw)) == want
 
 
 # ----------------------------------------------------------- real-jax contract
 def test_real_make_mesh_and_shard_map(mesh8):
-    """Integration: the installed JAX (whichever line) passes through compat."""
+    """Integration: the installed JAX passes through compat."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -238,7 +156,7 @@ def test_real_make_mesh_and_shard_map(mesh8):
         lambda x: jax.lax.psum(x, "data"), mesh=mesh8,
         in_specs=P("data"), out_specs=P(None), check_vma=False)
     with compat.set_mesh(mesh8):
-        out = compat.jit(f, donate_argnums=())(jnp.arange(8.0))
+        out = jax.jit(f)(jnp.arange(8.0))
     want = np.arange(8.0).reshape(4, 2).sum(axis=0)  # psum over the 4 blocks
     np.testing.assert_allclose(np.asarray(out), want)
 
@@ -256,4 +174,3 @@ def test_real_cost_analysis_is_dict():
 def test_real_backend_probe():
     assert compat.backend() in ("cpu", "gpu", "tpu")
     assert compat.interpret_kernels() == (compat.backend() != "tpu")
-    assert compat.jax_version() >= (0, 4, 0)

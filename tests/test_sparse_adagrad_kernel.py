@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.common import compat
 from repro.kernels.sparse_adagrad import dedup_aggregate, fused_sparse_adagrad
 from repro.kernels.sparse_adagrad.ref import (
     dedup_aggregate_ref, fused_update_ref,
@@ -25,13 +24,6 @@ from repro.kernels.sparse_adagrad.ref import (
 from repro.optim.sparse_adagrad import (
     dedup_compact_rows, set_use_kernel, sparse_adagrad_apply, use_kernel,
 )
-
-
-# the fused update addresses rows via scalar-prefetched ids; the same probe
-# gates the production use_kernel default (optim.use_kernel)
-needs_prefetch = pytest.mark.skipif(
-    not compat.has_scalar_prefetch(),
-    reason="no Pallas scalar-prefetch grid spec in this JAX")
 
 
 @pytest.fixture
@@ -60,7 +52,6 @@ def _mk(rng, N, D, n, dtype=jnp.float32, frac_pad=0.2):
 # fused update kernel vs oracle
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float16, jnp.bfloat16])
-@needs_prefetch
 def test_fused_update_matches_ref_dtypes(dtype):
     rng = np.random.default_rng(0)
     table, gsq, ids, grads = _mk(rng, 64, 32, 20, dtype)
@@ -78,7 +69,6 @@ def test_fused_update_matches_ref_dtypes(dtype):
     [-1, -1, -1, -1],                # all pads: bitwise no-op
     [2],                             # single row
 ])
-@needs_prefetch
 def test_fused_update_pad_rows_are_noops(ids_np):
     rng = np.random.default_rng(1)
     N, D = 16, 24
@@ -99,7 +89,6 @@ def test_fused_update_pad_rows_are_noops(ids_np):
                                   np.asarray(gsq)[untouched])
 
 
-@needs_prefetch
 def test_fused_update_d_tiling():
     """D divisible by a tile (256) exercises the multi-column d-outer grid."""
     rng = np.random.default_rng(2)
@@ -127,7 +116,6 @@ def test_dedup_aggregate_matches_ref(n, D):
                                rtol=1e-5, atol=1e-6)
 
 
-@needs_prefetch
 def test_dedup_then_fused_equals_apply_with_duplicates():
     """Raw duplicated ids through dedup→fused == sparse_adagrad_apply."""
     rng = np.random.default_rng(4)
@@ -149,7 +137,6 @@ def test_dedup_then_fused_equals_apply_with_duplicates():
 # ---------------------------------------------------------------------------
 # optim dispatch layer
 # ---------------------------------------------------------------------------
-@needs_prefetch
 def test_apply_kernel_path_matches_jnp_path():
     rng = np.random.default_rng(5)
     N, D, n = 50, 24, 40
@@ -243,7 +230,6 @@ def _batches(kg, cfg, n, seed=0):
             for _ in range(n)]
 
 
-@needs_prefetch
 def test_store_train_step_kernel_matches_jnp_all_stores(kernel_on):
     """Acceptance: with use_kernel on, store_train_step numerics match the
     jnp path to fp32 tolerance on Dense, Sharded and Replicated stores."""
@@ -292,7 +278,6 @@ def test_store_train_step_kernel_matches_jnp_all_stores(kernel_on):
                                rtol=2e-5, atol=2e-6)
 
 
-@needs_prefetch
 def test_capacity_bounded_defer_matches_full_buffer(kernel_on):
     """A pend buffer smaller than the workspace (dedup-before-defer) must
     produce the same flushed table as a workspace-sized buffer, as long as
@@ -322,7 +307,6 @@ def test_capacity_bounded_defer_matches_full_buffer(kernel_on):
                                np.asarray(full.gsq), rtol=2e-5, atol=2e-6)
 
 
-@needs_prefetch
 def test_hogwild_smoke_with_kernel(kernel_on):
     """2-trainer Hogwild over the kernel-enabled stores runs and learns."""
     kg = _small_kg()

@@ -14,7 +14,7 @@ from repro.models.steps import (
     serve_abstract_args, train_abstract_args,
 )
 from repro.models.transformer import build_model
-from repro.common.compat import cost_analysis, jit as cjit, set_mesh
+from repro.common.compat import cost_analysis, set_mesh
 
 RNG = np.random.default_rng(0)
 
@@ -38,7 +38,7 @@ def test_train_step_on_mesh(mesh8):
         bdefs = input_defs(cfg, shape, model)
         batch = {k: jnp.asarray(RNG.integers(0, cfg.vocab_size, d.shape), d.dtype)
                  for k, d in bdefs.items()}
-        jstep = cjit(step, donate_argnums=(0, 1))
+        jstep = jax.jit(step, donate_argnums=(0, 1))
         p2, o2, m = jstep(params, opt_state, batch)
         p3, o3, m2 = jstep(p2, o2, batch)
     assert np.isfinite(float(m["loss"])) and np.isfinite(float(m2["loss"]))
