@@ -1,0 +1,14 @@
+"""adagrad_dedup_ms_per_step.train: device time of the operations under the
+program's ``kge.adagrad_dedup`` scope (the duplicate-id aggregation of every
+sparse-Adagrad update, entity and relation tables), per step of the window
+(ms). An operation is under the scope when its name or its stats (the op
+name the compiler keeps) contain it."""
+
+SCOPE = ("kge.adagrad_dedup",)
+
+
+def read(ctx):
+    seconds = ctx["trace"].kernel_seconds(SCOPE)
+    if not ctx.get("steps") or seconds <= 0:
+        return None
+    return 1e3 * seconds / ctx["steps"]

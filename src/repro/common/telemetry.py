@@ -29,10 +29,14 @@ every layer reports into:
   program change either way.
 
 Timing is ``time.perf_counter`` throughout (monotonic; never jumps with wall
-clock). Under jit, ``span()`` brackets *tracing* (it runs once, when the
-function is traced) — that is deliberate: trace/compile phases show up once
-in the timeline, and host-side phases (sample, dispatch, hooks) are measured
-every step by the runtime's own spans.
+clock). Spans time host work only (sampling, copies, dispatch, waits,
+hooks): Python inside a jitted function runs once, at trace time, so the
+step's device phases are ``jax.named_scope`` names in the program instead
+(``kge.*``, core/step.py and optim/sparse_adagrad.py), which the profiler
+puts on the device's ops. While tracing is on, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name when JAX is already loaded, so
+the same span lands on the profiler's host thread lines, on the profiler's
+clock, beside the device trace. This module never imports JAX itself.
 
 Metric-name stability: every name emitted by the repo is listed in
 ``KNOWN_METRICS`` (exact) or ``KNOWN_PREFIXES`` (families). The validators
@@ -49,6 +53,7 @@ import contextlib
 import json
 import math
 import os
+import sys
 import threading
 import time
 from typing import Dict, Optional
@@ -155,19 +160,32 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if JAX is already loaded, else None.
+    Looked up, never imported: this module stays importable without JAX."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
 class _Span:
-    __slots__ = ("_reg", "_name", "_t0")
+    __slots__ = ("_reg", "_name", "_t0", "_ann")
 
     def __init__(self, reg: "MetricsRegistry", name: str):
         self._reg = reg
         self._name = name
+        ann = reg._annotation
+        self._ann = None if ann is None else ann(name)
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         reg = self._reg
         reg._emit_event({
             "name": self._name, "ph": "X", "pid": _PID,
@@ -184,7 +202,8 @@ class MetricsRegistry:
     All mutation goes through one lock; reads used on hot paths (``enabled``,
     ``trace_on``) are plain attribute loads. ``max_events`` bounds trace
     memory — past it, events are counted into
-    ``telemetry/trace_events_dropped`` instead of stored.
+    ``telemetry/trace_events_dropped`` instead of stored. With ``trace``
+    on, spans are also profiler annotations (``_profiler_annotation``).
     """
 
     def __init__(self, enabled: bool = True, trace: bool = False,
@@ -192,6 +211,7 @@ class MetricsRegistry:
         self.enabled = enabled
         self.trace_on = trace
         self.max_events = max_events
+        self._annotation = _profiler_annotation() if trace else None
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
         self.counters: Dict[str, float] = {}
@@ -246,15 +266,6 @@ class MetricsRegistry:
         if not (self.enabled and self.trace_on):
             return _NULL_SPAN
         return _Span(self, name)
-
-    def instant(self, name: str) -> None:
-        if not (self.enabled and self.trace_on):
-            return
-        self._emit_event({
-            "name": name, "ph": "i", "s": "t", "pid": _PID,
-            "tid": threading.get_ident(),
-            "ts": (time.perf_counter() - self._t0) * 1e6,
-        })
 
     def set_track_name(self, label: str, tid: Optional[int] = None) -> None:
         if not (self.enabled and self.trace_on):
@@ -327,7 +338,8 @@ def set_registry(reg: MetricsRegistry) -> MetricsRegistry:
 
 
 def enable(trace: bool = False) -> MetricsRegistry:
-    """Install a fresh enabled registry (optionally collecting trace spans)."""
+    """Install a fresh enabled registry (optionally collecting trace spans,
+    which are then also profiler annotations if JAX is loaded)."""
     set_registry(MetricsRegistry(enabled=True, trace=trace))
     return _REGISTRY
 
@@ -368,10 +380,6 @@ def trace_inc(name: str, n: float) -> None:
 
 def span(name: str):
     return _REGISTRY.span(name)
-
-
-def instant(name: str) -> None:
-    _REGISTRY.instant(name)
 
 
 def set_track_name(label: str) -> None:

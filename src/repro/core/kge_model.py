@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.common import telemetry
 from repro.common.config import KGEConfig
 from repro.core.sampling import MODES, KGBatch
 from repro.core.step import store_apply_grads, store_grads, store_train_step
@@ -161,8 +162,16 @@ def train_step(
     return state_from_stores(state, stores), metrics
 
 
+def _named(fn, *args, **kw):
+    """``functools.partial`` under ``fn``'s name, so the compiled step's
+    name stack reads ``jit(<fn name>)`` and not ``jit(<unknown>)``."""
+    step = functools.update_wrapper(functools.partial(fn, *args, **kw), fn)
+    del step.__wrapped__  # jit reads the partial's own signature (argument names)
+    return step
+
+
 def make_train_step(cfg: KGEConfig, pairwise_fn=None):
-    return jax.jit(functools.partial(train_step, cfg, pairwise_fn=pairwise_fn))
+    return jax.jit(_named(train_step, cfg, pairwise_fn=pairwise_fn))
 
 
 # --------------------------------------------------------------------------
@@ -197,18 +206,20 @@ def apply_step(cfg: KGEConfig, state: KGEState, batch, grads) -> KGEState:
 
 def make_hogwild_step(cfg: KGEConfig, pairwise_fn=None):
     """(grad_fn, apply_fn) pair for ``train_loop(..., split_step=...)``."""
-    g = jax.jit(functools.partial(grad_step, cfg, pairwise_fn=pairwise_fn))
-    a = jax.jit(functools.partial(apply_step, cfg))
+    g = jax.jit(_named(grad_step, cfg, pairwise_fn=pairwise_fn))
+    a = jax.jit(_named(apply_step, cfg))
     return g, a
 
 
 def batch_to_device(batch: KGBatch) -> Dict[str, jnp.ndarray]:
-    return {
-        "h": jnp.asarray(batch.h, jnp.int32),
-        "r": jnp.asarray(batch.r, jnp.int32),
-        "t": jnp.asarray(batch.t, jnp.int32),
-        "neg": jnp.asarray(batch.neg, jnp.int32),
-    }
+    """The host-to-device copy of one batch (span ``pipeline/to_device``)."""
+    with telemetry.span("pipeline/to_device"):
+        return {
+            "h": jnp.asarray(batch.h, jnp.int32),
+            "r": jnp.asarray(batch.r, jnp.int32),
+            "t": jnp.asarray(batch.t, jnp.int32),
+            "neg": jnp.asarray(batch.neg, jnp.int32),
+        }
 
 
 # --------------------------------------------------------------------------

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.common import telemetry
 from repro.common.config import KGEConfig
 from repro.core.graph_part import PartitionBook
 from repro.core.rel_part import RelationPartition
@@ -104,24 +105,25 @@ class JointSampler(TripletSampler):
         return pos[idx, col]
 
     def sample(self) -> KGBatch:
-        cfg = self.cfg
-        pos = self.positives(cfg.batch_size)
-        ng = cfg.n_neg_groups
-        k = cfg.neg_sample_size
-        n_deg = int(round(k * cfg.neg_deg_ratio))
-        neg = np.empty((MODES, ng, k), dtype=np.int64)
-        for m in range(MODES):
-            for g in range(ng):
-                u = self._uniform_negs(k - n_deg)
-                d = self._inbatch_negs(pos, n_deg, m)
-                neg[m, g] = np.concatenate([u, d])
-        return KGBatch(
-            h=pos[:, 0].copy(),
-            r=pos[:, 1].copy(),
-            t=pos[:, 2].copy(),
-            neg=neg,
-            n_groups=ng,
-        )
+        with telemetry.span("sampler/sample"):
+            cfg = self.cfg
+            pos = self.positives(cfg.batch_size)
+            ng = cfg.n_neg_groups
+            k = cfg.neg_sample_size
+            n_deg = int(round(k * cfg.neg_deg_ratio))
+            neg = np.empty((MODES, ng, k), dtype=np.int64)
+            for m in range(MODES):
+                for g in range(ng):
+                    u = self._uniform_negs(k - n_deg)
+                    d = self._inbatch_negs(pos, n_deg, m)
+                    neg[m, g] = np.concatenate([u, d])
+            return KGBatch(
+                h=pos[:, 0].copy(),
+                r=pos[:, 1].copy(),
+                t=pos[:, 2].copy(),
+                neg=neg,
+                n_groups=ng,
+            )
 
 
 class NaiveSampler(TripletSampler):

@@ -44,7 +44,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common import telemetry
 from repro.common.config import KGEConfig
 from repro.core import losses as L
 from repro.core import scores as S
@@ -54,6 +53,7 @@ from repro.embeddings.table import emb_init_scale
 Stores = Dict[str, object]  # "entity", "rel", optional "proj", "shared"
 
 
+@jax.named_scope("kge.score_grad")
 def store_grads(
     cfg: KGEConfig,
     stores: Stores,
@@ -76,6 +76,8 @@ def store_grads(
     workspaces already pulled during the previous step — the gathers are
     skipped and gradients are computed against those one-step-stale rows
     (the depth-1 staleness contract, ``prefetch_workspaces``).
+
+    Its device ops carry the scope ``kge.score_grad``.
     """
     ctx = S.ShardCtx(None) if ctx is None else ctx
     scale = emb_init_scale(cfg)
@@ -238,10 +240,10 @@ def store_train_step(
     The composition flush → ``store_grads`` → ``store_apply_grads`` on one
     store set (grads applied to the stores they were computed against).
 
-    Phase boundaries are telemetry spans. Under jit they bracket *tracing*
-    (this Python runs once, when the step is traced), so they appear once in
-    the timeline as the trace-time cost of each phase; in eager execution
-    (tests, debugging) they time the real phases every call.
+    The phases are named scopes on the device ops: ``kge.flush``,
+    ``kge.score_grad`` (in ``store_grads``) and ``kge.apply``; inside the
+    first and last, every sparse update splits into ``kge.adagrad_dedup``
+    and ``kge.adagrad_update`` (optim/sparse_adagrad.py).
 
     When the entity store defers (T5), ``metrics["pend_dropped"]`` reports
     the store's capacity-bounded defer drop count — updates silently lost
@@ -250,13 +252,12 @@ def store_train_step(
     """
     # ---- 1. flush deferred updates (T5) before gathering
     stores = dict(stores)
-    with telemetry.span("step/flush"):
+    with jax.named_scope("kge.flush"):
         stores["entity"] = stores["entity"].flush()
-    with telemetry.span("step/grad"):
-        grads, metrics = store_grads(
-            cfg, stores, batch, neg_mode=neg_mode, ctx=ctx,
-            n_servers=n_servers, pairwise_fn=pairwise_fn)
-    with telemetry.span("step/apply"):
+    grads, metrics = store_grads(
+        cfg, stores, batch, neg_mode=neg_mode, ctx=ctx,
+        n_servers=n_servers, pairwise_fn=pairwise_fn)
+    with jax.named_scope("kge.apply"):
         new_stores = store_apply_grads(stores, batch, grads)
     ent = new_stores["entity"]
     if getattr(ent, "defer", False) and getattr(ent, "pend_dropped", None) is not None:
@@ -312,15 +313,15 @@ def store_pipelined_step(
     pipelined path requires T5 defer off (the pipeline already provides the
     overlap, and both contracts are single-writer — enforced by
     ``core.distributed.make_program``). ``next_batch`` only needs the
-    ``ent_ids``/``rel_ids`` addresses.
+    ``ent_ids``/``rel_ids`` addresses. Scopes: ``kge.score_grad``,
+    ``kge.prefetch``, ``kge.apply``.
     """
-    with telemetry.span("step/grad"):
-        grads, metrics = store_grads(
-            cfg, stores, batch, ctx=ctx, n_servers=n_servers,
-            pairwise_fn=pairwise_fn, prefetched=prefetched)
-    with telemetry.span("step/prefetch"):
+    grads, metrics = store_grads(
+        cfg, stores, batch, ctx=ctx, n_servers=n_servers,
+        pairwise_fn=pairwise_fn, prefetched=prefetched)
+    with jax.named_scope("kge.prefetch"):
         new_pf = prefetch_workspaces(stores, next_batch)
-    with telemetry.span("step/apply"):
+    with jax.named_scope("kge.apply"):
         new_stores = store_apply_grads(stores, batch, grads)
     ent = new_stores["entity"]
     if getattr(ent, "coalesce", False):

@@ -13,8 +13,9 @@ sampling work. ``stats()`` exposes the three backpressure signals (queue
 depth, cumulative producer wait, cumulative consumer wait) that say which
 side of the pipeline is the bottleneck. The same signals are mirrored into
 the process telemetry registry (``pipeline/*`` — common/telemetry.py) when
-it is enabled, and each producer's ``sample_fn`` call is a ``pipeline/sample``
-span on that worker's own trace track. Wait accounting uses
+it is enabled, each producer's ``sample_fn`` call is a ``pipeline/sample``
+span on that worker's own trace track, and each blocking ``get()`` is a
+``pipeline/consumer_wait`` span on the consumer's track. Wait accounting uses
 ``time.perf_counter`` (monotonic — wall-clock jumps never corrupt rates).
 
 ``Prefetcher`` (the original single-producer, double-buffered prefetcher) is
@@ -123,11 +124,12 @@ class WorkerPool:
         try:
             return self.q.get_nowait()
         except queue.Empty:
-            t0 = time.perf_counter()
-            try:
-                return self.q.get(timeout=timeout)
-            finally:
-                self._add_wait("_consumer_wait", t0)
+            with telemetry.span("pipeline/consumer_wait"):
+                t0 = time.perf_counter()
+                try:
+                    return self.q.get(timeout=timeout)
+                finally:
+                    self._add_wait("_consumer_wait", t0)
 
     def peek(self, timeout: Optional[float] = None):
         """One-batch lookahead: the next batch WITHOUT consuming it.

@@ -81,6 +81,7 @@ def pairwise_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
         interpret=interpret,
+        name=f"kge_pairwise_{mode}",
     )(o, negs)
 
 
@@ -133,6 +134,7 @@ def l1_bwd_pallas(o, negs, g, *, bm=128, bn=128, bk=128, interpret=False):
         out_specs=pl.BlockSpec((bm, bk), lambda i, d, j: (i, d)),
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
         interpret=interpret,
+        name="kge_l1_bwd_do",
     )(o, negs, g)
     dn = pl.pallas_call(
         _l1_dn_kernel,
@@ -145,5 +147,6 @@ def l1_bwd_pallas(o, negs, g, *, bm=128, bn=128, bk=128, interpret=False):
         out_specs=pl.BlockSpec((bn, bk), lambda j, d, i: (j, d)),
         out_shape=jax.ShapeDtypeStruct((K, D), jnp.float32),
         interpret=interpret,
+        name="kge_l1_bwd_dn",
     )(o, negs, g)
     return do, dn

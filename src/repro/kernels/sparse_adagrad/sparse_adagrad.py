@@ -46,6 +46,11 @@ Grid orders (revisit-safety):
 Every BlockSpec keeps its last two block dimensions divisible by (8, 128)
 or equal to the array's, the rule Mosaic enforces when it compiles for the
 chip (tests/test_tpu_compile.py compiles both kernels at FB15k widths).
+
+Each kernel is named after the device scope its caller gives it
+(``kge.adagrad_update``, ``kge.adagrad_dedup``; optim/sparse_adagrad.py), so
+its custom call carries the scope in its own name: a v5e profile names each
+op by its HLO text, which holds no scope metadata.
 """
 
 from __future__ import annotations
@@ -131,6 +136,7 @@ def fused_update_pallas(
         # operand order: rmap, tids, grads, mask, table, gsq -> alias table/gsq
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="kge.adagrad_update",
     )(rmap, tile_ids, grad_tiles, mask, table, gsq)
 
 
@@ -202,4 +208,5 @@ def dedup_aggregate_pallas(
         out_shape=[jax.ShapeDtypeStruct((n, D), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1), jnp.int32)],
         interpret=interpret,
+        name="kge.adagrad_dedup",
     )(ids.reshape(n, 1), ids.reshape(1, n), grads)

@@ -93,9 +93,12 @@ class LoggingHook(Hook):
             self.qdepth = stats["queue_depth"]
         if i % self.log_every:
             return
+        # the loss is read first: that syncs on step i, so the rate counts
+        # finished steps and not dispatched ones
+        loss = float(metrics["loss"])
         done = i - self.start
         dt = max(time.perf_counter() - self.t0, 1e-9)
-        line = f"step {i:6d} loss {float(metrics['loss']):8.4f} ({done/dt:6.1f} steps/s"
+        line = f"step {i:6d} loss {loss:8.4f} ({done/dt:6.1f} steps/s"
         if self.batch_size:
             line += f", {done*self.batch_size/dt:9.0f} triplets/s"
             if self.saw_drops:
@@ -383,14 +386,16 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
                 nxt, _ = src.peek()
                 with telemetry.span("engine/step"):
                     state, metrics = step_fn(state, batch, nxt)
-                for h in hooks:
-                    h.on_step(i, state, metrics, stats)
+                with telemetry.span("engine/hooks"):
+                    for h in hooks:
+                        h.on_step(i, state, metrics, stats)
         else:
             for i, (batch, stats) in zip(range(start + 1, n_steps + 1), src):
                 with telemetry.span("engine/step"):
                     state, metrics = step_fn(state, batch)
-                for h in hooks:
-                    h.on_step(i, state, metrics, stats)
+                with telemetry.span("engine/hooks"):
+                    for h in hooks:
+                        h.on_step(i, state, metrics, stats)
     finally:
         if prefetch:
             src.close()

@@ -161,8 +161,7 @@ def hogwild_train_loop(
                     if stop.is_set():
                         return
             while not stop.is_set() and todo.claim():
-                with telemetry.span("runtime/wait_batch"):
-                    batch_stats = _get(pool, stop)
+                batch_stats = _get(pool, stop)
                 if batch_stats is None:  # shut down while waiting for a batch
                     todo.unclaim()
                     return

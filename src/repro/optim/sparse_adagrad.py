@@ -192,7 +192,9 @@ def sparse_adagrad_apply(
 
     Accepts raw (possibly duplicated, possibly padded) workspace ids; every
     ``EmbeddingStore.apply_sparse_grads`` lowers to this call, which picks
-    the fused Pallas path or the jnp path per the ``use_kernel`` flag.
+    the fused Pallas path or the jnp path per the ``use_kernel`` flag. On
+    either path the device ops of the two halves carry the scopes
+    ``kge.adagrad_dedup`` and ``kge.adagrad_update``.
     """
     ids = ids.astype(jnp.int32)
     if _resolve(use_kernel):
@@ -203,12 +205,16 @@ def sparse_adagrad_apply(
             dedup_aggregate, fused_sparse_adagrad,
         )
 
-        uid, agg = dedup_aggregate(ids, grads)
-        return fused_sparse_adagrad(table, gsq, uid, agg, lr, eps)
+        with jax.named_scope("kge.adagrad_dedup"):
+            uid, agg = dedup_aggregate(ids, grads)
+        with jax.named_scope("kge.adagrad_update"):
+            return fused_sparse_adagrad(table, gsq, uid, agg, lr, eps)
     telemetry.inc("optim/dispatch_jnp")
-    uid, agg = segment_aggregate_rows(ids, grads)
-    new_table, st = sparse_adagrad_update_rows(
-        table, AdagradState(gsq), uid, agg, lr, eps)
+    with jax.named_scope("kge.adagrad_dedup"):
+        uid, agg = segment_aggregate_rows(ids, grads)
+    with jax.named_scope("kge.adagrad_update"):
+        new_table, st = sparse_adagrad_update_rows(
+            table, AdagradState(gsq), uid, agg, lr, eps)
     return new_table, st.gsq
 
 

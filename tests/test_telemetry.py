@@ -1,11 +1,18 @@
 """Telemetry stack: registry thread-safety, trace schema, TelemetryHook
-JSONL output, Hogwild per-trainer tracks, and pend-overflow surfacing."""
+JSONL output, Hogwild per-trainer tracks, pend-overflow surfacing, and the
+train path's host spans (profiler annotations while tracing) and device
+scopes."""
 
+import ast
 import json
+import sys
 import threading
+import time
 import warnings
 
+import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.common import telemetry
 from repro.common.telemetry import (
@@ -13,7 +20,7 @@ from repro.common.telemetry import (
 )
 from repro.embeddings.store import DenseStore
 from repro.launch.engine import (
-    LoggingHook, MetricsHook, TelemetryHook, train_loop,
+    Hook, LoggingHook, MetricsHook, TelemetryHook, train_loop,
 )
 
 
@@ -199,6 +206,12 @@ def test_telemetry_hook_inert_when_disabled(tmp_path):
     assert not mpath.exists()  # no registry enabled -> no file, no error
 
 
+def _slow_batch():
+    # slower than the trainers' no-op steps, so they wait for batches
+    time.sleep(0.002)
+    return (), None
+
+
 def test_hogwild_per_trainer_tracks_and_exact_step_counts(tmp_path):
     def grad_fn(state, batch):
         return 0, {"loss": 0.0}
@@ -213,7 +226,7 @@ def test_hogwild_per_trainer_tracks_and_exact_step_counts(tmp_path):
         state = train_loop(
             None, 0, None, n_steps, hooks=[hook],
             n_trainers=n_trainers, n_samplers=2,
-            sampler_factory=lambda wid: (lambda: ((), None)),
+            sampler_factory=lambda wid: _slow_batch,
             split_step=(grad_fn, apply_fn))
         assert state == n_steps  # every step's apply landed exactly once
         assert reg.counters["runtime/steps"] == n_steps
@@ -224,9 +237,10 @@ def test_hogwild_per_trainer_tracks_and_exact_step_counts(tmp_path):
               if e.get("ph") == "M"}
     for tid in range(n_trainers):
         assert f"trainer-{tid}" in tracks, tracks
-    # every trainer's grad/apply phases appear as spans on some track
+    # every trainer's grad/apply phases and its waits for a batch appear as
+    # spans on some track
     names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
-    assert {"runtime/grad", "runtime/apply", "runtime/wait_batch"} <= names
+    assert {"runtime/grad", "runtime/apply", "pipeline/consumer_wait"} <= names
 
 
 # ---------------------------------------------------------------------------
@@ -274,3 +288,152 @@ def test_logging_hook_warns_once_on_pend_drops():
     assert issubclass(pend_warns[0].category, RuntimeWarning)
     assert "pend_drop" not in lines[0]
     assert "pend_drop 7" in lines[1] and "pend_drop 9" in lines[2]
+
+
+def test_logging_hook_rate_counts_the_loss_sync():
+    class SlowLoss:  # a device scalar whose read waits for the step
+        def __float__(self):
+            time.sleep(0.05)
+            return 0.5
+
+    lines = []
+    hook = LoggingHook(log_every=1, batch_size=1, print_fn=lines.append)
+    hook.on_step(1, None, {"loss": SlowLoss()}, None)
+    rate = float(lines[0].split("(")[1].split()[0])
+    assert rate <= 1 / 0.05  # the clock is read after the sync, not before
+
+
+# ---------------------------------------------------------------------------
+# the train path's host spans and device scopes
+# ---------------------------------------------------------------------------
+def test_telemetry_imports_only_the_standard_library():
+    src = open(telemetry.__file__).read()
+    mods = set()
+    for node in ast.walk(ast.parse(src)):
+        if isinstance(node, ast.Import):
+            mods |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            mods.add(node.module.split(".")[0])
+    assert mods - {"__future__"} <= set(sys.stdlib_module_names), mods
+
+
+def _tiny_kge(**kw):
+    from repro.common.config import KGEConfig
+
+    base = dict(name="t", model="transe_l2", n_entities=64, n_relations=6,
+                dim=16, batch_size=16, neg_sample_size=8, neg_group_size=8,
+                loss="self_adv")
+    base.update(kw)
+    return KGEConfig(**base)
+
+
+def _train_path(cfg, n_steps, step_fn=None, delay=0.0):
+    """``train_loop`` fed the way the trainer feeds it: the sampler and the
+    host-to-device copy in the producer thread."""
+    import numpy as np
+
+    from repro.core.kge_model import batch_to_device, init_state, make_train_step
+    from repro.core.sampling import JointSampler
+
+    rng = np.random.default_rng(0)
+    trip = np.stack([rng.integers(0, cfg.n_entities, 200),
+                     rng.integers(0, cfg.n_relations, 200),
+                     rng.integers(0, cfg.n_entities, 200)], 1)
+    sampler = JointSampler(trip, cfg.n_entities, cfg, rng)
+
+    def feed():
+        time.sleep(delay)  # a sampler slower than the step: the loop waits
+        return batch_to_device(sampler.sample()), None
+
+    seen = []
+
+    class Seen(Hook):
+        def on_step(self, i, state, metrics, stats):
+            seen.append(i)
+
+    state = init_state(cfg, jax.random.key(0))
+    train_loop(step_fn or make_train_step(cfg), state, feed, n_steps, hooks=[Seen()])
+    return seen
+
+
+def test_train_loop_records_host_spans_on_their_threads():
+    cfg = _tiny_kge()
+    with telemetry.active(trace=True) as reg:
+        _train_path(cfg, 6, step_fn=lambda st, b: (st, {"loss": 0.0}), delay=0.01)
+        doc = reg.trace_json()
+    tracks = {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+              if e.get("ph") == "M"}
+    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    where = {}
+    for e in spans:
+        where.setdefault(e["name"], set()).add(tracks[e["tid"]])
+    main = threading.main_thread().name
+    assert where["pipeline/sample"] == {"sampler-0"}
+    assert where["sampler/sample"] == where["pipeline/to_device"] == {"sampler-0"}
+    assert where["pipeline/consumer_wait"] == where["engine/hooks"] == {main}
+    assert where["engine/step"] == {main}
+    outer = [e for e in spans if e["name"] == "pipeline/sample"]
+    for name in ("sampler/sample", "pipeline/to_device"):
+        inner = [e for e in spans if e["name"] == name]
+        assert len(inner) >= 6
+        for e in inner:  # each nests in one pipeline/sample span
+            assert any(o["ts"] <= e["ts"] and e["ts"] + e["dur"] <= o["ts"] + o["dur"]
+                       for o in outer), (name, e)
+    hooks = [e for e in spans if e["name"] == "engine/hooks"]
+    assert len(hooks) == 6
+
+
+def test_train_path_records_nothing_with_telemetry_off():
+    reg = telemetry.get_registry()
+    assert not reg.enabled and reg._annotation is None
+    seen = _train_path(_tiny_kge(), 3, step_fn=lambda st, b: (st, {"loss": 0.0}))
+    assert seen == [1, 2, 3]
+    assert reg.trace_json()["traceEvents"] == []
+    assert reg.counters == {} and reg.gauges == {}
+
+
+def test_span_is_a_profiler_annotation_while_tracing(tmp_path):
+    from jax.profiler import ProfileData
+
+    reg = MetricsRegistry(enabled=True, trace=True)
+    assert reg._annotation is jax.profiler.TraceAnnotation
+    assert MetricsRegistry(enabled=True)._annotation is None
+    jax.profiler.start_trace(str(tmp_path))
+    with reg.span("sampler/sample"):
+        time.sleep(0.01)
+    jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    found = [e for p in ProfileData.from_file(str(path)).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events if e.name == "sampler/sample"]
+    assert len(found) == 1 and found[0].duration_ns >= 1e7
+    assert [e["name"] for e in reg.trace_json()["traceEvents"]
+            if e.get("ph") == "X"] == ["sampler/sample"]
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["jnp", "fused"])
+def test_train_step_ops_carry_the_phase_scopes(fused):
+    import numpy as np
+
+    from repro.core.kge_model import batch_to_device, init_state, make_train_step
+    from repro.core.sampling import JointSampler
+    from repro.optim.sparse_adagrad import set_use_kernel
+
+    cfg = _tiny_kge()
+    set_use_kernel(fused)
+    try:
+        step = make_train_step(cfg)
+        state = init_state(cfg, jax.random.key(0), overlap=True)
+        trip = np.zeros((4, 3), np.int64)
+        batch = batch_to_device(JointSampler(trip, cfg.n_entities, cfg,
+                                             np.random.default_rng(0)).sample())
+        text = step.lower(state, batch).as_text(debug_info=True)
+    finally:
+        set_use_kernel(None)
+    for scope in ("kge.score_grad", "kge.adagrad_dedup", "kge.adagrad_update",
+                  "kge.flush", "kge.apply"):
+        assert f"/{scope}/" in text, scope
+    assert "jit(train_step)/" in text and "jit(<unknown>)" not in text
+    # the T5 deferred apply is a sparse update inside the flush
+    assert "kge.flush/kge.adagrad_dedup/" in text
+    assert "kge.flush/kge.adagrad_update/" in text
