@@ -21,6 +21,7 @@ import numpy as np
 from benchmarks.common import emit, time_loop
 from repro.common import compat, telemetry
 from repro.core.scores import pairwise_scores
+from repro.kernels.sparse_adagrad.ops import _tile_rows
 from repro.optim.sparse_adagrad import sparse_adagrad_apply, use_kernel
 
 
@@ -51,10 +52,11 @@ def run_sparse_adagrad():
     """Fused sparse-Adagrad kernel vs the jnp sort/segment/scatter path.
 
     Wall-clock rows/s is the jnp path (the one that runs on this backend);
-    the fused kernel's number is its analytic HBM traffic — dedup reads the
-    workspace twice, the update makes ONE pass over the touched rows with
-    table/gsq aliased in place — against the XLA-measured bytes of the
-    compiled jnp update (which rewrites the full table unless XLA can alias).
+    the fused kernel's number is its analytic HBM traffic — the grouping
+    scatter-adds the workspace into per-tile gradient rows, the update makes
+    ONE pass over the touched tiles with table/gsq aliased in place —
+    against the XLA-measured bytes of the compiled jnp update (which
+    rewrites the full table unless XLA can alias).
     """
     fast = os.environ.get("BENCH_FAST", "1") != "0"
     N, D, n = (50_000, 256, 4096) if fast else (500_000, 400, 16_384)
@@ -76,10 +78,12 @@ def run_sparse_adagrad():
 
     itm = 4  # f32
     u = len({int(i) for i in ids_np if i >= 0})
-    # dedup kernel: read grads + ids, write agg + cnt (≈ 2 workspace passes);
-    # fused update: read agg workspace + (table, gsq) rows, write them back —
-    # only the u touched rows move, never the other N - u.
-    bytes_fused = (2 * n * D + n * D + 4 * u * D) * itm
+    tr = _tile_rows(jnp.float32)
+    u_tiles = len({int(i) // tr for i in ids_np if i >= 0})
+    # grouping: read the n workspace rows, scatter-add them into the
+    # grad-tile rows; fused update: read those rows + the touched (table,
+    # gsq) tiles, write the tiles back — the other tiles never move.
+    bytes_fused = (n * D + 2 * u_tiles * tr * D + 4 * u_tiles * tr * D) * itm
     # jnp lower bound if XLA aliased perfectly: sort+segment (≈3 workspace
     # passes) + gather/scatter of touched rows (gsq twice: add then re-gather)
     bytes_jnp_alias = (3 * n * D + 6 * u * D) * itm

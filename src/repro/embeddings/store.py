@@ -135,16 +135,14 @@ def _coalesce_remote(co_ids, co_grads, req, g_remote):
     buffered ``(co_ids[p], co_grads[p])`` and this step's ``(req[p],
     g_remote[p])`` are dedup-aggregated and compacted back into the fixed
     per-peer capacity by ``dedup_compact_rows``. Uniques beyond capacity are
-    dropped (counted — surfaced as the ``push_dropped`` step metric). The
-    jnp dedup path is forced: the merge runs under ``vmap`` over peers,
-    where the Pallas dedup kernel's scalar-prefetch layout does not apply.
+    dropped (counted — surfaced as the ``push_dropped`` step metric).
 
     Returns ``(ids (P, Ck), grads (P, Ck, d), n_dropped scalar)``.
     """
     def merge(ci, cg, ri, rg):
         ids = jnp.concatenate([ci, ri.astype(jnp.int32)])
         g = jnp.concatenate([cg, rg.astype(cg.dtype)], axis=0)
-        return dedup_compact_rows(ids, g, ci.shape[0], use_kernel=False)
+        return dedup_compact_rows(ids, g, ci.shape[0])
 
     ids, grads, dropped = jax.vmap(merge)(co_ids, co_grads, req, g_remote)
     return ids, grads, jnp.sum(dropped)

@@ -1,3 +1,3 @@
-from repro.kernels.sparse_adagrad.ops import dedup_aggregate, fused_sparse_adagrad
+from repro.kernels.sparse_adagrad.ops import fused_sparse_adagrad
 
-__all__ = ["dedup_aggregate", "fused_sparse_adagrad"]
+__all__ = ["fused_sparse_adagrad"]

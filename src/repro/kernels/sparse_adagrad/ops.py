@@ -1,19 +1,16 @@
-"""jit'd wrappers for the sparse-Adagrad kernels: padding, pad-remap, tiles.
+"""jit'd wrapper for the sparse-Adagrad kernel: grouping, pad-remap, tiles.
 
-``fused_sparse_adagrad`` is a drop-in for the jnp
-``segment-dedup → sparse_adagrad_update_rows`` pair when the ids are already
-deduplicated; ``dedup_aggregate`` is the kernel replacement for the
-argsort/segment_sum dedup itself. optim/sparse_adagrad.py routes through
-these behind its ``use_kernel`` flag — nothing else should call them.
+``fused_sparse_adagrad`` is the fused replacement for the jnp
+``segment-dedup → sparse_adagrad_update_rows`` pair: it takes raw workspace
+ids, sums the gradients of duplicate rows while grouping them by tile
+(``_group_tiles``), and updates each touched tile once. optim/sparse_adagrad.py
+routes through it behind its ``use_kernel`` flag — nothing else should call
+it.
 
-Contracts:
-  * ``fused_sparse_adagrad``: valid ids must be UNIQUE (of a duplicated
-    row only one gradient would land — see optim/sparse_adagrad.py). Pad slots
-    (id < 0) may appear anywhere; they are exact no-ops. The table is never
-    padded or copied — the kernel updates the aliased buffers in place, one
-    memory tile of ``tr`` rows at a time (``_group_tiles``).
-  * ``dedup_aggregate``: any ids (duplicates + pads); returns the in-place
-    layout of ref.dedup_aggregate_ref.
+Contract: any ids — duplicates allowed (their gradients are summed in float32
+before the one Adagrad step of the row), pad slots (id < 0) anywhere, exact
+no-ops. The table is never padded or copied — the kernel updates the aliased
+buffers in place, one memory tile of ``tr`` rows at a time.
 """
 
 from __future__ import annotations
@@ -24,10 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.compat import interpret_kernels as _interpret
-from repro.kernels.sparse_adagrad.sparse_adagrad import (
-    dedup_aggregate_pallas,
-    fused_update_pallas,
-)
+from repro.kernels.sparse_adagrad.sparse_adagrad import fused_update_pallas
 
 
 def _row_tile(D: int) -> int:
@@ -66,28 +60,33 @@ def _tile_rows(dtype) -> int:
 
 def _group_tiles(ids: jnp.ndarray, grads: jnp.ndarray, tr: int,
                  n_tiles: int):
-    """Group unique row ids by the ``tr``-row tile that holds them.
+    """Group row ids by the ``tr``-row tile that holds them, summing the
+    gradients of duplicate ids.
 
-    Returns ``(tile_ids (n_tiles,), grad_tiles (n_tiles*tr, D),
+    Returns ``(tile_ids (n_tiles,), grad_tiles (n_tiles*tr, D) float32,
     mask (n_tiles*tr, 1))``: the distinct touched tiles, ascending and
     compacted with -1 pads, and row ``r`` of slot ``s`` at ``s*tr + r``
-    holding that row's gradient (mask 1) or nothing (mask 0). Distinct
-    rows give distinct (slot, row) pairs, so the scatter never collides.
+    holding the sum of that row's gradients (mask 1) or nothing (mask 0).
+    Every occurrence of a row id lands on the same (slot, row) pair, so the
+    scatter-add is the whole duplicate aggregation. The ids are sorted with
+    pads last, so the destinations never decrease and the scatters are told
+    so (``indices_are_sorted``).
     """
     n, D = grads.shape
     valid = ids >= 0
-    tile = jnp.where(valid, ids // tr, jnp.iinfo(jnp.int32).max)
-    order = jnp.argsort(tile)
-    st, sv, sid = tile[order], valid[order], ids[order]
+    order = jnp.argsort(jnp.where(valid, ids, jnp.iinfo(jnp.int32).max))
+    sv, sid = valid[order], ids[order]
+    st = sid // tr
     first = sv & jnp.concatenate([jnp.ones((1,), bool), st[1:] != st[:-1]])
     slot = jnp.cumsum(first) - 1
     tile_ids = jnp.full((n_tiles,), -1, jnp.int32).at[
         jnp.where(first, slot, n_tiles)].set(st, mode="drop")
     dest = jnp.where(sv, slot * tr + sid % tr, n_tiles * tr)
-    grad_tiles = jnp.zeros((n_tiles * tr, D), grads.dtype).at[dest].set(
-        grads[order], mode="drop")
+    grad_tiles = jnp.zeros((n_tiles * tr, D), jnp.float32).at[dest].add(
+        grads[order].astype(jnp.float32), mode="drop",
+        indices_are_sorted=True)
     mask = jnp.zeros((n_tiles * tr, 1), jnp.int32).at[dest].set(
-        1, mode="drop")
+        1, mode="drop", indices_are_sorted=True)
     return tile_ids, grad_tiles, mask
 
 
@@ -100,49 +99,21 @@ def fused_sparse_adagrad(
     eps: float = 1e-10,
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused in-place row update. ids (n,) with -1 pads, valid ids unique."""
+    """Fused in-place row update. ids (n,): duplicates and -1 pads allowed.
+
+    The grouping (sort and scatter-add) runs under the device scope
+    ``kge.adagrad_dedup``, the kernel under ``kge.adagrad_update``.
+    """
     if ids.shape[0] == 0:
         return table, gsq
     interpret = _interpret() if interpret is None else interpret
     tr = _tile_rows(table.dtype)
     n_tiles = min(ids.shape[0], -(-table.shape[0] // tr))
-    tile_ids, grad_tiles, mask = _group_tiles(
-        ids.astype(jnp.int32), grads.astype(table.dtype), tr, n_tiles)
-    return fused_update_pallas(
-        table, gsq, _pad_remap(tile_ids), tile_ids, grad_tiles, mask,
-        lr=lr, eps=eps, bd=_row_tile(table.shape[1]), interpret=interpret)
-
-
-def _dedup_tiles(n: int, D: int) -> Tuple[int, int, int]:
-    bi = min(128, max(8, 1 << (n - 1).bit_length()))
-    bd = min(128, max(8, 1 << (D - 1).bit_length())) if D < 128 \
-        else _row_tile(D) if D % 128 == 0 else 128
-    return bi, bi, bd
-
-
-def dedup_aggregate(
-    ids: jnp.ndarray,
-    grads: jnp.ndarray,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Kernel dedup: (uid, agg) in the in-place layout (see ref.py).
-
-    Slot i keeps ids[i] iff it is the first occurrence; its grad row becomes
-    the sum over all occurrences; other slots get (-1, zeros).
-    """
-    n = ids.shape[0]
-    if n == 0:
-        return ids.astype(jnp.int32), grads
-    interpret = _interpret() if interpret is None else interpret
-    D = grads.shape[1]
-    bi, bj, bd = _dedup_tiles(n, D)
-    npad = (-n) % max(bi, bj)
-    dpad = (-D) % bd
-    idp = jnp.pad(ids.astype(jnp.int32), (0, npad), constant_values=-1)
-    gp = jnp.pad(grads, ((0, npad), (0, dpad)))
-    agg, cnt = dedup_aggregate_pallas(idp, gp, bi=bi, bj=bj, bd=bd,
-                                      interpret=interpret)
-    agg, cnt = agg[:n, :D], cnt[:n, 0]
-    first = (cnt == 0) & (ids >= 0)
-    uid = jnp.where(first, ids, -1).astype(jnp.int32)
-    return uid, jnp.where(first[:, None], agg, 0.0).astype(grads.dtype)
+    with jax.named_scope("kge.adagrad_dedup"):
+        tile_ids, grad_tiles, mask = _group_tiles(
+            ids.astype(jnp.int32), grads, tr, n_tiles)
+    with jax.named_scope("kge.adagrad_update"):
+        return fused_update_pallas(
+            table, gsq, _pad_remap(tile_ids), tile_ids,
+            grad_tiles.astype(table.dtype), mask, lr=lr, eps=eps,
+            bd=_row_tile(table.shape[1]), interpret=interpret)

@@ -9,13 +9,8 @@ Contracts (mirrored by ops.py, matching optim/sparse_adagrad.py semantics):
     Slots with ids[i] < 0 are no-ops. Updates use the *updated* accumulator
     (the DGL-KE §3.4 order). Returns (new_table, new_gsq).
 
-``dedup_aggregate_ref(ids, grads)``
-    In-place dedup: slot i keeps its id iff it is the *first* occurrence of
-    that id; its gradient becomes the sum over all occurrences. Non-first and
-    pad (< 0) slots get id -1 and a zero row. Unlike the sort-based
-    ``segment_aggregate_rows`` the slots are NOT compacted — valid slots stay
-    at their original positions, which is what lets the fused update kernel
-    consume either layout.
+With duplicated ids, ``optim.sparse_adagrad.segment_aggregate_rows``
+followed by this oracle is what ``ops.fused_sparse_adagrad`` computes.
 """
 
 from __future__ import annotations
@@ -42,14 +37,3 @@ def fused_update_ref(
     new_table = table.astype(jnp.float32).at[safe].add(-step, mode="drop")
     return new_table.astype(table.dtype), new_gsq.astype(gsq.dtype)
 
-
-def dedup_aggregate_ref(
-    ids: jnp.ndarray, grads: jnp.ndarray
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    ids = ids.astype(jnp.int32)
-    valid = ids >= 0
-    match = (ids[:, None] == ids[None, :]) & valid[:, None]
-    first = valid & ~jnp.any(jnp.tril(match, k=-1), axis=1)
-    agg = match.astype(jnp.float32) @ grads.astype(jnp.float32)
-    uid = jnp.where(first, ids, -1).astype(jnp.int32)
-    return uid, jnp.where(first[:, None], agg, 0.0).astype(grads.dtype)

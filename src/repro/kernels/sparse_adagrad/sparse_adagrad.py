@@ -1,10 +1,11 @@
-"""Pallas TPU kernels: fused sparse-Adagrad tile update + dedup-aggregate.
+"""Pallas TPU kernel: fused sparse-Adagrad tile update.
 
 The update half of every DGL-KE step (paper §2, §3.4) is a per-row Adagrad
 over the deduplicated touched rows. The jnp path costs ~4 HBM passes over
 those rows (scatter-add into gsq, gather of the updated accumulator,
 scatter-add into the table) plus the argsort/segment_sum dedup machinery.
-Two kernels fuse this:
+One kernel fuses the update; its wrapper (ops.fused_sparse_adagrad) sums
+duplicate rows in the same sort and scatter that groups rows by tile.
 
 ``fused_update_pallas``
     One pass per touched *tile*: a tile is the ``tr``-row group of the table
@@ -19,38 +20,27 @@ Two kernels fuse this:
     addressed through scalar-prefetched tile ids (the ``index_map`` gathers
     block ``rmap[i]`` of the full table).
 
-    Hazard contract (enforced by the wrapper, documented in
-    optim/sparse_adagrad.py): valid tile ids MUST be unique — the block
-    pipeline prefetches ahead, so a duplicate tile would be re-read before
-    the previous write lands. The wrapper groups the unique row ids by tile,
-    which makes the tile ids unique. Pad slots (tile id < 0) are remapped by
-    the wrapper to the *previous* valid slot's tile: consecutive same-index
-    blocks stay resident in VMEM (no refetch/reflush), and the kernel simply
-    skips the write, so a pad is a true no-op with no read-after-write hazard.
+    Hazard contract (enforced by the wrapper): valid tile ids MUST be
+    unique — the block pipeline prefetches ahead, so a duplicate tile would
+    be re-read before the previous write lands. The wrapper groups the row
+    ids by tile, which makes the tile ids unique. Pad slots (tile id < 0)
+    are remapped by the wrapper to the *previous* valid slot's tile:
+    consecutive same-index blocks stay resident in VMEM (no
+    refetch/reflush), and the kernel simply skips the write, so a pad is a
+    true no-op with no read-after-write hazard.
 
-``dedup_aggregate_pallas``
-    Replaces argsort + segment_sum for the fixed-workspace case with a tiled
-    O(n²) match-matrix contraction that rides the MXU:
-    ``match[i,j] = (ids[i] == ids[j])``; ``agg = match @ grads``; a slot is
-    a *first occurrence* iff no earlier slot matches. Slots keep their
-    original positions (no compaction), so the output feeds straight into
-    the fused update's wrapper (ops.fused_sparse_adagrad).
-
-Grid orders (revisit-safety):
-  * update: ``(D/bd, T)`` with d OUTERMOST — within one d-column, pad slots
-    revisit the immediately preceding block; across columns blocks never
-    alias.
-  * dedup: ``(n/bi, D/bd, n/bj)`` with j innermost — agg/cnt accumulate in
-    the revisited output block, flushed when (i, d) advances.
+Grid order (revisit-safety): ``(D/bd, T)`` with d OUTERMOST — within one
+d-column, pad slots revisit the immediately preceding block; across columns
+blocks never alias.
 
 Every BlockSpec keeps its last two block dimensions divisible by (8, 128)
 or equal to the array's, the rule Mosaic enforces when it compiles for the
-chip (tests/test_tpu_compile.py compiles both kernels at FB15k widths).
+chip (tests/test_tpu_compile.py compiles the kernel at FB15k widths).
 
-Each kernel is named after the device scope its caller gives it
-(``kge.adagrad_update``, ``kge.adagrad_dedup``; optim/sparse_adagrad.py), so
-its custom call carries the scope in its own name: a v5e profile names each
-op by its HLO text, which holds no scope metadata.
+The kernel is named after the device scope its wrapper gives it
+(``kge.adagrad_update``), so its custom call carries the scope in its own
+name: a v5e profile names each op by its HLO text, which holds no scope
+metadata.
 """
 
 from __future__ import annotations
@@ -138,75 +128,3 @@ def fused_update_pallas(
         interpret=interpret,
         name="kge.adagrad_update",
     )(rmap, tile_ids, grad_tiles, mask, table, gsq)
-
-
-# ---------------------------------------------------------------------------
-# tiled dedup-aggregate
-# ---------------------------------------------------------------------------
-def _dedup_kernel(idr_ref, idc_ref, g_ref, agg_ref, cnt_ref, *, bj: int):
-    i = pl.program_id(0)
-    d = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init_agg():
-        agg_ref[...] = jnp.zeros_like(agg_ref)
-
-    @pl.when(jnp.logical_and(j == 0, d == 0))
-    def _init_cnt():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    ids_i = idr_ref[...]  # (bi, 1)
-    ids_j = idc_ref[...]  # (1, bj)
-    match = (ids_i == ids_j) & (ids_i >= 0)  # (bi, bj); pads never match
-    agg_ref[...] += jax.lax.dot_general(
-        match.astype(jnp.float32), g_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(d == 0)
-    def _count_earlier():
-        bi = ids_i.shape[0]
-        gi = i * bi + jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 0)
-        gj = j * bj + jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 1)
-        earlier = match & (gj < gi)
-        cnt_ref[...] += jnp.sum(earlier.astype(jnp.int32), axis=1,
-                                keepdims=True)
-
-
-def dedup_aggregate_pallas(
-    ids: jnp.ndarray,
-    grads: jnp.ndarray,
-    *,
-    bi: int = 128,
-    bj: int = 128,
-    bd: int = 128,
-    interpret: bool = False,
-):
-    """(n,) ids x (n, D) grads -> (agg (n, D) f32, cnt (n, 1) i32).
-
-    ``agg[i]`` = sum of grads over every slot whose id equals ids[i];
-    ``cnt[i]`` = number of *earlier* slots with the same id (0 = first
-    occurrence). Caller pads n to lcm(bi, bj) and D to bd multiples.
-    """
-    n = ids.shape[0]
-    D = grads.shape[1]
-    assert n % bi == 0 and n % bj == 0 and D % bd == 0
-    grid = (n // bi, D // bd, n // bj)
-    return pl.pallas_call(
-        functools.partial(_dedup_kernel, bj=bj),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bi, 1), lambda i, d, j: (i, 0)),
-            pl.BlockSpec((1, bj), lambda i, d, j: (0, j)),
-            pl.BlockSpec((bj, bd), lambda i, d, j: (j, d)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bi, bd), lambda i, d, j: (i, d)),
-            pl.BlockSpec((bi, 1), lambda i, d, j: (i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((n, D), jnp.float32),
-                   jax.ShapeDtypeStruct((n, 1), jnp.int32)],
-        interpret=interpret,
-        name="kge.adagrad_dedup",
-    )(ids.reshape(n, 1), ids.reshape(1, n), grads)

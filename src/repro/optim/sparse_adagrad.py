@@ -10,9 +10,10 @@ Two implementations sit behind one entry point, ``sparse_adagrad_apply``
 
 * the **jnp path** — argsort + ``segment_sum`` dedup followed by scatter-add
   row updates (≈4 HBM passes over the touched rows per table per step);
-* the **fused Pallas path** (kernels/sparse_adagrad) — a tiled on-device
-  dedup-aggregate plus ONE pass per touched 8-row memory tile that reads the
-  aggregated gradients, bumps ``gsq``, computes the step from the *updated*
+* the **fused Pallas path** (kernels/sparse_adagrad) — one sort and
+  scatter-add that groups the raw ids by 8-row memory tile and sums the
+  gradients of duplicate rows, then ONE pass per touched tile that reads the
+  summed gradients, bumps ``gsq``, computes the step from the *updated*
   accumulator (the DGL-KE order) and writes the tile back, with ``table``
   and ``gsq`` aliased in place.
 
@@ -102,31 +103,10 @@ def segment_aggregate_rows(
     return uid.astype(jnp.int32), agg
 
 
-def aggregate_rows(
-    ids: jnp.ndarray,
-    grads: jnp.ndarray,
-    use_kernel: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Dedup duplicate ids, summing their gradient rows.
-
-    Both paths return fixed-size (uid, agg) where each surviving slot holds a
-    unique id with the aggregated gradient and every other slot holds -1;
-    layouts differ (jnp compacts+sorts, the kernel keeps original positions)
-    but both are valid inputs to ``sparse_adagrad_update_rows`` /
-    ``fused_sparse_adagrad``, which ignore slot order.
-    """
-    if _resolve(use_kernel):
-        from repro.kernels.sparse_adagrad import dedup_aggregate
-
-        return dedup_aggregate(ids.astype(jnp.int32), grads)
-    return segment_aggregate_rows(ids.astype(jnp.int32), grads)
-
-
 def dedup_compact_rows(
     ids: jnp.ndarray,
     grads: jnp.ndarray,
     capacity: int,
-    use_kernel: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dedup + compact into a ``capacity``-slot buffer (T5 pend buffers).
 
@@ -135,7 +115,7 @@ def dedup_compact_rows(
     buffer for the expected unique count and may surface ``n_dropped`` as a
     diagnostic; the deferred-update memory bound is the point (ROADMAP T5).
     """
-    uid, agg = aggregate_rows(ids, grads, use_kernel)
+    uid, agg = segment_aggregate_rows(ids.astype(jnp.int32), grads)
     first = uid >= 0
     rank = jnp.cumsum(first) - 1
     dest = jnp.where(first, rank, capacity)  # non-uniques -> dropped slot
@@ -163,9 +143,8 @@ def sparse_adagrad_update_rows(
     Duplicate-id hazard: valid ids MUST be unique. Adagrad is nonlinear —
     with duplicates the scatter-add sums every occurrence into ``gsq``
     *before* the step is computed, so each duplicate's step is divided by the
-    full aggregated denominator and the rows double-count it; the fused
-    Pallas kernel additionally has a read-after-write pipeline hazard on
-    duplicate rows. Dedup (``aggregate_rows``) must precede this call —
+    full aggregated denominator and the rows double-count it. Dedup
+    (``segment_aggregate_rows``) must precede this call —
     ``sparse_adagrad_apply`` composes the two correctly.
     """
     valid = (ids >= 0)[:, None]
@@ -194,21 +173,17 @@ def sparse_adagrad_apply(
     ``EmbeddingStore.apply_sparse_grads`` lowers to this call, which picks
     the fused Pallas path or the jnp path per the ``use_kernel`` flag. On
     either path the device ops of the two halves carry the scopes
-    ``kge.adagrad_dedup`` and ``kge.adagrad_update``.
+    ``kge.adagrad_dedup`` (on the fused path the tile grouping's sort and
+    scatter-add) and ``kge.adagrad_update``.
     """
     ids = ids.astype(jnp.int32)
     if _resolve(use_kernel):
         # dispatch decisions happen at trace time — the counters say which
         # path each traced step function took (docs/TELEMETRY.md)
         telemetry.inc("optim/dispatch_fused")
-        from repro.kernels.sparse_adagrad import (
-            dedup_aggregate, fused_sparse_adagrad,
-        )
+        from repro.kernels.sparse_adagrad import fused_sparse_adagrad
 
-        with jax.named_scope("kge.adagrad_dedup"):
-            uid, agg = dedup_aggregate(ids, grads)
-        with jax.named_scope("kge.adagrad_update"):
-            return fused_sparse_adagrad(table, gsq, uid, agg, lr, eps)
+        return fused_sparse_adagrad(table, gsq, ids, grads, lr, eps)
     telemetry.inc("optim/dispatch_jnp")
     with jax.named_scope("kge.adagrad_dedup"):
         uid, agg = segment_aggregate_rows(ids, grads)

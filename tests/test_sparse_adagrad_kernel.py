@@ -1,7 +1,8 @@
 """Fused sparse-Adagrad Pallas kernels vs jnp references (interpret mode).
 
 Layers covered, bottom-up:
-  * kernels/sparse_adagrad ops vs ref.py oracles (dtypes, pads, duplicates);
+  * kernels/sparse_adagrad ops vs ref.py oracles (dtypes, pads), and raw
+    duplicated ids vs segment dedup followed by the oracle;
   * optim.sparse_adagrad_apply kernel-vs-jnp path parity;
   * optim.dedup_compact_rows capacity bound + overflow accounting;
   * store_train_step numerics with the kernel enabled on all three stores
@@ -17,12 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.sparse_adagrad import dedup_aggregate, fused_sparse_adagrad
-from repro.kernels.sparse_adagrad.ref import (
-    dedup_aggregate_ref, fused_update_ref,
-)
+from repro.kernels.sparse_adagrad import fused_sparse_adagrad
+from repro.kernels.sparse_adagrad.ref import fused_update_ref
 from repro.optim.sparse_adagrad import (
-    dedup_compact_rows, set_use_kernel, sparse_adagrad_apply, use_kernel,
+    dedup_compact_rows, segment_aggregate_rows, set_use_kernel,
+    sparse_adagrad_apply, use_kernel,
 )
 
 
@@ -102,30 +102,47 @@ def test_fused_update_d_tiling():
 
 
 # ---------------------------------------------------------------------------
-# dedup-aggregate kernel vs oracle
+# duplicate ids: summed in the tile grouping's scatter-add
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n,D", [(7, 5), (33, 40), (64, 128)])
-def test_dedup_aggregate_matches_ref(n, D):
+@pytest.mark.parametrize("n,D,case", [
+    (7, 5, "dups"), (33, 40, "dups"), (64, 128, "dups"), (512, 400, "dups"),
+    (33, 40, "all_same"), (64, 128, "bf16"),
+])
+def test_fused_update_aggregates_duplicates(n, D, case):
+    """Raw duplicated ids through the fused path == segment-dedup followed
+    by the fused-update oracle."""
     rng = np.random.default_rng(3)
-    ids = jnp.asarray(rng.integers(-1, 10, size=n), jnp.int32)  # many dups
+    N = max(10, n // 2)  # many duplicates; few tiles keep interpret mode fast
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    table = jnp.asarray(rng.standard_normal((N, D)), dtype)
+    gsq = jnp.asarray(np.abs(rng.standard_normal((N, D))), dtype)
+    ids_np = (np.full(n, 5) if case == "all_same"
+              else rng.integers(-1, N, size=n))
+    ids = jnp.asarray(ids_np, jnp.int32)
     grads = jnp.asarray(rng.standard_normal((n, D)), jnp.float32)
-    uid_k, agg_k = dedup_aggregate(ids, grads)
-    uid_r, agg_r = dedup_aggregate_ref(ids, grads)
-    np.testing.assert_array_equal(np.asarray(uid_k), np.asarray(uid_r))
-    np.testing.assert_allclose(np.asarray(agg_k), np.asarray(agg_r),
-                               rtol=1e-5, atol=1e-6)
+    t_k, q_k = fused_sparse_adagrad(table, gsq, ids, grads, 0.1)
+    uid, agg = segment_aggregate_rows(ids, grads)
+    t_r, q_r = fused_update_ref(table, gsq, uid, agg, 0.1)
+    tol = _tol(dtype)
+    np.testing.assert_allclose(np.asarray(t_k, np.float32),
+                               np.asarray(t_r, np.float32), **tol)
+    np.testing.assert_allclose(np.asarray(q_k, np.float32),
+                               np.asarray(q_r, np.float32), **tol)
+    untouched = sorted(set(range(N)) - {int(i) for i in ids_np if i >= 0})
+    np.testing.assert_array_equal(np.asarray(t_k)[untouched],
+                                  np.asarray(table)[untouched])
 
 
 def test_dedup_then_fused_equals_apply_with_duplicates():
-    """Raw duplicated ids through dedup→fused == sparse_adagrad_apply."""
+    """Raw duplicated ids straight into the fused path == the jnp
+    sparse_adagrad_apply (segment dedup, then row updates)."""
     rng = np.random.default_rng(4)
     N, D, n = 20, 16, 30
     table = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
     gsq = jnp.asarray(np.abs(rng.standard_normal((N, D))), jnp.float32)
     ids = jnp.asarray(rng.integers(-1, N, size=n), jnp.int32)
     grads = jnp.asarray(rng.standard_normal((n, D)), jnp.float32)
-    uid, agg = dedup_aggregate(ids, grads)
-    t_k, q_k = fused_sparse_adagrad(table, gsq, uid, agg, 0.1)
+    t_k, q_k = fused_sparse_adagrad(table, gsq, ids, grads, 0.1)
     t_j, q_j = sparse_adagrad_apply(table, gsq, ids, grads, 0.1,
                                     use_kernel=False)
     np.testing.assert_allclose(np.asarray(t_k), np.asarray(t_j),
@@ -166,14 +183,14 @@ def test_use_kernel_override_and_env(monkeypatch):
     assert use_kernel() is False
 
 
-@pytest.mark.parametrize("use_k", [False, True])
-def test_dedup_compact_rows_bounds_capacity(use_k):
+@pytest.mark.parametrize("cap", [6, 8])
+def test_dedup_compact_rows_bounds_capacity(cap):
     rng = np.random.default_rng(6)
     n, D = 24, 8
     ids = jnp.asarray(rng.integers(0, 6, size=n), jnp.int32)  # ≤6 uniques
     grads = jnp.asarray(rng.standard_normal((n, D)), jnp.float32)
-    cids, cgrads, dropped = dedup_compact_rows(ids, grads, 8, use_kernel=use_k)
-    assert cids.shape == (8,) and cgrads.shape == (8, D)
+    cids, cgrads, dropped = dedup_compact_rows(ids, grads, cap)
+    assert cids.shape == (cap,) and cgrads.shape == (cap, D)
     assert int(dropped) == 0
     got = {int(i): np.asarray(g) for i, g in zip(cids, cgrads) if i >= 0}
     want = {}
@@ -187,7 +204,7 @@ def test_dedup_compact_rows_bounds_capacity(use_k):
 def test_dedup_compact_rows_counts_overflow():
     ids = jnp.arange(10, dtype=jnp.int32)  # 10 uniques, capacity 4
     grads = jnp.ones((10, 3), jnp.float32)
-    cids, _, dropped = dedup_compact_rows(ids, grads, 4, use_kernel=False)
+    cids, _, dropped = dedup_compact_rows(ids, grads, 4)
     assert int((cids >= 0).sum()) == 4
     assert int(dropped) == 6
 

@@ -20,9 +20,7 @@ import pytest
 
 from repro.kernels.kge_score.kge_score import l1_bwd_pallas, pairwise_pallas
 from repro.kernels.kge_score.ops import _tiles
-from repro.kernels.sparse_adagrad.ops import (
-    _tile_rows, dedup_aggregate, fused_sparse_adagrad,
-)
+from repro.kernels.sparse_adagrad.ops import _tile_rows, fused_sparse_adagrad
 
 N_ENTITIES = 14_951  # FB15k (configs/kge_datasets.py)
 N_WORKSPACE = 3_584
@@ -70,6 +68,8 @@ def _compile(fn, *args, **jit_kw):
 
 @pytest.mark.parametrize("d", WIDTHS)
 def test_fused_sparse_adagrad_compiles_in_place(row_major, d):
+    """The raw workspace ids go in, duplicates and all: the grouping's
+    scatter-add sums duplicate rows with no table-sized temporary."""
     table = row_major((N_ENTITIES, d))
     compiled = _compile(
         lambda t, q, i, g: fused_sparse_adagrad(t, q, i, g, 0.1,
@@ -88,12 +88,6 @@ def test_fused_sparse_adagrad_compiles_in_place(row_major, d):
     n_tiles = min(N_WORKSPACE, -(-N_ENTITIES // tr))
     d_lanes = -(-d // 128) * 128
     assert mem.temp_size_in_bytes <= n_tiles * tr * d_lanes * 4 + (4 << 20)
-
-
-@pytest.mark.parametrize("d", WIDTHS)
-def test_dedup_aggregate_compiles(row_major, d):
-    _compile(lambda i, g: dedup_aggregate(i, g, interpret=False),
-             row_major((N_WORKSPACE,), jnp.int32), row_major((N_WORKSPACE, d)))
 
 
 @pytest.mark.parametrize("d", WIDTHS)
