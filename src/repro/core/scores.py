@@ -210,8 +210,8 @@ def neg_o(
     if model == "transr":
         assert r_proj is not None and rel_dim > 0
         ds = e.shape[-1]
-        m = r_proj.reshape(r_proj.shape[0], ds, rel_dim)
-        pe = ctx.psum(jnp.einsum("bd,bdr->br", e, m))  # (b, rel_dim) replicated
+        m = r_proj.reshape(r_proj.shape[:-1] + (ds, rel_dim))
+        pe = ctx.psum(jnp.einsum("...bd,...bdr->...br", e, m))  # replicated
         if corrupt == "tail":
             return pe + _gather_full_r(r, ctx)
         return pe - _gather_full_r(r, ctx)  # replicated; negatives projected too
@@ -230,10 +230,10 @@ def neg_o(
 
 
 def _gather_full_r(r_slice: jnp.ndarray, ctx: ShardCtx) -> jnp.ndarray:
-    """All-gather a (b, ds) dim slice into the full replicated (b, dim)."""
+    """All-gather a (..., b, ds) dim slice into the full replicated (..., b, dim)."""
     if ctx.axis is None:
         return r_slice
-    return jax.lax.all_gather(r_slice, ctx.axis, axis=1, tiled=True)
+    return jax.lax.all_gather(r_slice, ctx.axis, axis=r_slice.ndim - 1, tiled=True)
 
 
 def pairwise_scores(
@@ -333,17 +333,39 @@ def negative_score(
     """(b, k) negative scores via the joint decomposition.
 
     ``pairwise_fn(mode, o, negs)`` defaults to the jnp reference; the Pallas
-    kernel wrapper (kernels/kge_score/ops.py) is drop-in.
+    kernel wrapper (kernels/kge_score/ops.py) is drop-in. TransR takes no
+    ``pairwise_fn`` (``projected_l2sq`` scores it) and also accepts a leading
+    group axis on every input: (g, b, .), (g, k, ds), (g, b, ds * rel_dim).
     """
     pw = pairwise_fn or pairwise_scores
     mode = PAIRWISE_OF[model]
     o = neg_o(model, h_or_t, r, corrupt, ctx, r_proj, rel_dim, emb_scale)
     if model == "transr":
-        # negatives must be projected per relation: (b, k, rel_dim)
-        ds = negs.shape[-1]
-        m = r_proj.reshape(r_proj.shape[0], ds, rel_dim)
-        pn = ctx.psum(jnp.einsum("kd,bdr->bkr", negs, m))  # replicated
-        d2 = jnp.sum(jnp.square(o[:, None, :] - pn), axis=-1)
+        # negatives must be projected per relation: (..., b, k, rel_dim)
+        m = r_proj.reshape(r_proj.shape[:-1] + (negs.shape[-1], rel_dim))
+        d2 = projected_l2sq(o, negs, m, ctx)
         return gamma - jnp.sqrt(d2 + 1e-12)  # already full-dim: no finish psum
     partial = pw(mode, o, negs)
     return finish_neg_scores(model, partial, gamma, ctx)
+
+
+def projected_l2sq(o: jnp.ndarray, negs: jnp.ndarray, m: jnp.ndarray,
+                   ctx: ShardCtx) -> jnp.ndarray:
+    """TransR's negative distances: sum_r (o_b - (negs_k @ M_b)_r)^2 for
+    every triplet b and shared candidate k; o (..., b, R), negs (..., k, ds),
+    m (..., b, ds, R) -> (..., b, k).
+
+    On a TPU with the dim unsharded, the ``kge.transr_score`` kernels
+    (kernels/kge_score) compute it without writing the (..., b, k, R)
+    projections; elsewhere, and when the projections are partial sums over
+    a dim-striped axis, the einsum below does.
+    """
+    if ctx.axis is None and compat.backend() == "tpu":
+        from repro.kernels.kge_score import transr_l2sq
+
+        lead = o.shape[:-2]
+        flat = lambda x: x.reshape((-1,) + x.shape[len(lead):])  # noqa: E731
+        d2 = transr_l2sq(flat(o), flat(negs), flat(m))
+        return d2.reshape(lead + d2.shape[1:])
+    pn = ctx.psum(jnp.einsum("...kd,...bdr->...bkr", negs, m))  # replicated
+    return jnp.sum(jnp.square(o[..., :, None, :] - pn), axis=-1)

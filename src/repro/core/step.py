@@ -49,6 +49,7 @@ from repro.core import losses as L
 from repro.core import scores as S
 from repro.core.sampling import MODES
 from repro.embeddings.table import emb_init_scale
+from repro.optim.sparse_adagrad import add_rows
 
 Stores = Dict[str, object]  # "entity", "rel", optional "proj", "shared"
 
@@ -96,6 +97,11 @@ def store_grads(
         ws = ent.gather(batch["ent_ids"])
         rel_ws = rel_store.gather(batch["rel_ids"])
     proj_ws = stores["proj"].gather(batch["rel_ids"]) if has_proj else None
+    # each triplet's projection row; the loss is differentiated w.r.t. these
+    # and ``add_rows`` sums their gradients onto the workspace rows (the
+    # transpose of this gather would be one scatter of 40,000-wide rows,
+    # which XLA runs serially on a TPU)
+    proj_rows = proj_ws[rel_slot] if has_proj else None
     shared_rows = stores["shared"].gather(rel_shared) if has_shared else None
     is_shared = (rel_shared >= 0)[:, None] if has_shared else None
 
@@ -113,12 +119,11 @@ def store_grads(
     )
 
     # ---- 3. loss + grads w.r.t. workspace rows ONLY (sparse, paper §2)
-    def loss_fn(ws_, rel_ws_, shared_rows_, proj_ws_):
+    def loss_fn(ws_, rel_ws_, shared_rows_, pr):
         h, t = ws_[h_slot], ws_[t_slot]
         r = rel_ws_[rel_slot]
         if is_shared is not None:
             r = jnp.where(is_shared, shared_rows_, r)
-        pr = None if proj_ws_ is None else proj_ws_[rel_slot]
         pos = S.positive_score(cfg.model, h, r, t, cfg.gamma, ctx,
                                r_proj=pr, rel_dim=cfg.rel_dim, emb_scale=scale)
 
@@ -152,7 +157,13 @@ def store_grads(
             rg = r.reshape(ng, gsz, -1)
             prg = None if pr is None else pr.reshape(ng, gsz, -1)
             negs = ws_[neg_slot[m]]  # (ng, k, d)
-            if sharded_negs:
+            if cfg.model == "transr":
+                # the group axis goes whole to projected_l2sq (the kernel's
+                # grid on a TPU), not through vmap
+                neg_out.append(S.negative_score(
+                    cfg.model, e, rg, negs, corrupt, cfg.gamma, ctx,
+                    r_proj=prg, rel_dim=cfg.rel_dim))
+            elif sharded_negs:
                 f = jax.vmap(lambda e1, r1, n1: S.negative_score_sharded(
                     cfg.model, e1, r1, n1, corrupt, cfg.gamma, ctx,
                     emb_scale=scale, pairwise_fn=pairwise_fn,
@@ -187,14 +198,15 @@ def store_grads(
     argnums = [0, 1] + ([2] if has_shared else []) + ([3] if has_proj else [])
     (loss, (pos_m, neg_m)), grads = jax.value_and_grad(
         loss_fn, argnums=tuple(argnums), has_aux=True
-    )(ws, rel_ws, shared_rows, proj_ws)
+    )(ws, rel_ws, shared_rows, proj_rows)
     gmap = dict(zip(argnums, grads))
 
     out = {"entity": gmap[0], "rel": gmap[1]}
     if has_shared:
         out["shared"] = gmap[2]
     if has_proj:
-        out["proj"] = gmap[3]
+        out["proj"] = add_rows(proj_ws.shape[0], rel_slot, gmap[3]).astype(
+            proj_ws.dtype)
     metrics = {"loss": loss, "pos_score": pos_m, "neg_score": neg_m}
     return out, metrics
 

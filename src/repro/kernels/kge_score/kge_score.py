@@ -150,3 +150,115 @@ def l1_bwd_pallas(o, negs, g, *, bm=128, bn=128, bk=128, interpret=False):
         name="kge_l1_bwd_dn",
     )(o, negs, g)
     return do, dn
+
+
+# ---------------------------------------------------------------------------
+# TransR: per-triplet projected distances to the group's shared negatives.
+# Each candidate must be projected by the matrix of every triplet of its
+# group, so there is no shared-candidate GEMM; the (B, K, R) projections
+# live only in VMEM, one projected dimension r at a time.
+#
+# Layout: the matrices come r-major, m_t (G, R, D, B), and the vectors o as
+# o_t (G, R, B), so that for each r the projections of all K candidates by
+# all B matrices are one (K, D) x (D, B) product, lane-dense in B, and the
+# squared distance accumulates into a resident (kc, B) block of the (G, K, B)
+# output over the innermost grid axis, which walks ``rb`` rows of R. Grid
+# (G, K/kc, R/rb). Matrix operands are cast to ``mxu_dtype``; products
+# accumulate in float32.
+# ---------------------------------------------------------------------------
+def _transr_fwd_kernel(o_ref, n_ref, m_ref, out_ref, *, rb: int, mxu_dtype):
+    r = pl.program_id(2)
+    negs = n_ref[0].astype(mxu_dtype)  # (kc, D)
+    acc = jnp.where(r == 0, 0.0, out_ref[0])  # (kc, B)
+    for i in range(rb):
+        pn = jnp.dot(negs, m_ref[0, i].astype(mxu_dtype),
+                     preferred_element_type=jnp.float32)  # (kc, B)
+        diff = o_ref[0, i : i + 1, :].astype(jnp.float32) - pn
+        acc = acc + diff * diff
+    out_ref[0] = acc
+
+
+def _transr_bwd_kernel(o_ref, n_ref, nt_ref, m_ref, g_ref, dm_ref, dn_ref,
+                       do_ref, *, rb: int, mxu_dtype):
+    r = pl.program_id(2)
+
+    @pl.when(r == 0)
+    def _init():
+        dn_ref[...] = jnp.zeros_like(dn_ref)
+
+    negs = n_ref[0].astype(mxu_dtype)  # (kc, D)
+    negs_t = nt_ref[0].astype(mxu_dtype)  # (D, kc)
+    g = g_ref[0].astype(jnp.float32)  # (kc, B) upstream
+    dn = jnp.zeros(dn_ref.shape[1:], jnp.float32)
+    for i in range(rb):
+        m = m_ref[0, i].astype(mxu_dtype)  # (D, B)
+        pn = jnp.dot(negs, m, preferred_element_type=jnp.float32)
+        diff = o_ref[0, i : i + 1, :].astype(jnp.float32) - pn  # (kc, B)
+        dpn = (-2.0 * g) * diff  # d(loss)/d(pn)
+        do_ref[0, 0, i : i + 1, :] = -jnp.sum(dpn, axis=0, keepdims=True)
+        dpn_m = dpn.astype(mxu_dtype)
+        dm_ref[0, 0, i] = jnp.dot(negs_t, dpn_m, preferred_element_type=jnp.float32)
+        dn += jax.lax.dot_general(dpn_m, m, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+    dn_ref[0] += dn
+
+
+def _transr_specs(o_t, negs, kc: int, rb: int):
+    G, R, B = o_t.shape
+    K, D = negs.shape[1:]
+    assert K % kc == 0 and R % rb == 0, (K, kc, R, rb)
+    specs = {
+        "o": pl.BlockSpec((1, rb, B), lambda g, c, r: (g, r, 0)),
+        "negs": pl.BlockSpec((1, kc, D), lambda g, c, r: (g, c, 0)),
+        "m": pl.BlockSpec((1, rb, D, B), lambda g, c, r: (g, r, 0, 0)),
+        "scores": pl.BlockSpec((1, kc, B), lambda g, c, r: (g, c, 0)),
+    }
+    return (G, K // kc, R // rb), specs
+
+
+def transr_fwd_pallas(o_t, negs, m_t, *, kc: int = 0, rb: int = 8,
+                      mxu_dtype=jnp.float32, interpret: bool = False):
+    """o_t (G, R, B), negs (G, K, D), m_t (G, R, D, B) -> (G, K, B) float32:
+    entry [g, k, b] is sum_r (o[g, b] - negs[g, k] @ M[g, b])_r^2, with
+    ``o_t[g, r, b] = o[g, b, r]`` and ``m_t[g, r, d, b] = M[g, b][d, r]``.
+    ``kc`` (0 = K) must divide K and ``rb`` divide R."""
+    grid, sp = _transr_specs(o_t, negs, kc or negs.shape[1], rb)
+    G, _, B = o_t.shape
+    return pl.pallas_call(
+        functools.partial(_transr_fwd_kernel, rb=rb, mxu_dtype=mxu_dtype),
+        grid=grid,
+        in_specs=[sp["o"], sp["negs"], sp["m"]],
+        out_specs=sp["scores"],
+        out_shape=jax.ShapeDtypeStruct((G, negs.shape[1], B), jnp.float32),
+        interpret=interpret,
+        name="kge.transr_score",
+    )(o_t, negs, m_t)
+
+
+def transr_bwd_pallas(o_t, negs, m_t, g, *, kc: int = 0, rb: int = 8,
+                      mxu_dtype=jnp.float32, interpret: bool = False):
+    """Gradients of ``transr_fwd_pallas`` given its upstream ``g`` (G, K, B),
+    the projections recomputed in VMEM. Returns float32 (d_m_t (G, K/kc, R,
+    D, B), d_negs (G, K, D), d_o_t (G, K/kc, R, B)): d_m_t and d_o_t hold one
+    partial sum per block of ``kc`` candidates."""
+    G, R, B = o_t.shape
+    K, D = negs.shape[1:]
+    kc = kc or K
+    grid, sp = _transr_specs(o_t, negs, kc, rb)
+    return pl.pallas_call(
+        functools.partial(_transr_bwd_kernel, rb=rb, mxu_dtype=mxu_dtype),
+        grid=grid,
+        in_specs=[sp["o"], sp["negs"],
+                  pl.BlockSpec((1, D, kc), lambda g, c, r: (g, 0, c)),
+                  sp["m"], sp["scores"]],
+        out_specs=[
+            pl.BlockSpec((1, 1, rb, D, B), lambda g, c, r: (g, c, r, 0, 0)),
+            sp["negs"],
+            pl.BlockSpec((1, 1, rb, B), lambda g, c, r: (g, c, r, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((G, K // kc, R, D, B), jnp.float32),
+                   jax.ShapeDtypeStruct((G, K, D), jnp.float32),
+                   jax.ShapeDtypeStruct((G, K // kc, R, B), jnp.float32)],
+        interpret=interpret,
+        name="kge.transr_score_bwd",
+    )(o_t, negs, jnp.swapaxes(negs, 1, 2), m_t, g)

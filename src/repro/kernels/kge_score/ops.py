@@ -9,6 +9,10 @@ Backward:
   l2sq : d_o = 2 (o · rowsum(g) − g @ negs) ; symmetric (plain GEMMs)
   l1   : Pallas kernels (kge_score.l1_bwd_pallas) — the jnp form would
          materialize (B, K, D) in HBM.
+
+``transr_l2sq`` is TransR's projected form (core/scores.projected_l2sq on a
+TPU): one forward kernel and one backward kernel that recomputes the
+projections, so the (G, B, K, R) projected negatives never reach HBM.
 """
 
 from __future__ import annotations
@@ -19,7 +23,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.compat import interpret_kernels as _interpret
-from repro.kernels.kge_score.kge_score import l1_bwd_pallas, pairwise_pallas
+from repro.kernels.kge_score.kge_score import (
+    l1_bwd_pallas,
+    pairwise_pallas,
+    transr_bwd_pallas,
+    transr_fwd_pallas,
+)
 
 
 def _pad_to(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
@@ -90,3 +99,65 @@ pairwise_scores_kernel.defvjp(_fwd, _bwd)
 def kernel_pairwise_fn(mode: str, o: jnp.ndarray, negs: jnp.ndarray):
     """Drop-in ``pairwise_fn`` for core/scores.negative_score."""
     return pairwise_scores_kernel(mode, o, negs)
+
+
+# ---------------------------------------------------------------------------
+# TransR projected distances
+# ---------------------------------------------------------------------------
+def _mxu_dtype():
+    """The operand precision a default-precision float32 matmul gets on the
+    platform: one bfloat16 pass on a TPU, float32 elsewhere — what the jnp
+    einsum the kernels replace gets, so the two differ in summation order."""
+    return jnp.float32 if _interpret() else jnp.bfloat16
+
+
+def _transr_blocks(R: int, K: int):
+    """(padded R, padded K, kc): R in blocks of 8 rows; candidates in blocks
+    of up to 512 (evaluation scores every entity)."""
+    kp = K if K <= 512 else -(-K // 512) * 512
+    return -(-R // 8) * 8, kp, min(kp, 512)
+
+
+def _pad(x, axis, size):
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - x.shape[axis])
+    return x if size == x.shape[axis] else jnp.pad(x, pad)
+
+
+def _r_major(o, negs, m):
+    """o (G, B, R) -> o_t (G, Rp, B); m (G, B, D, R) -> m_t (G, Rp, D, B);
+    negs padded to Kp candidates. Zero rows of R add nothing to a distance."""
+    rp, kp, kc = _transr_blocks(o.shape[2], negs.shape[1])
+    o_t = _pad(jnp.swapaxes(o, 1, 2), 1, rp)
+    m_t = _pad(jnp.transpose(m, (0, 3, 2, 1)), 1, rp)
+    return o_t, _pad(negs, 1, kp), m_t, kc
+
+
+@jax.custom_vjp
+def transr_l2sq(o: jnp.ndarray, negs: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:
+    """(G, B, R), (G, K, D), (G, B, D, R) -> (G, B, K): the squared distance
+    sum_r (o_b - negs_k @ M_b)_r^2 of every triplet to every shared negative
+    of its group (kernel ``kge.transr_score``)."""
+    return _transr_fwd(o, negs, m)[0]
+
+
+def _transr_fwd(o, negs, m):
+    o_t, negs_p, m_t, kc = _r_major(o, negs, m)
+    out = transr_fwd_pallas(o_t, negs_p, m_t, kc=kc, mxu_dtype=_mxu_dtype(),
+                            interpret=_interpret())
+    return jnp.swapaxes(out[:, : negs.shape[1]], 1, 2), (o, negs, m)
+
+
+def _transr_bwd(res, g):
+    o, negs, m = res
+    R, K = o.shape[2], negs.shape[1]
+    o_t, negs_p, m_t, kc = _r_major(o, negs, m)
+    g_t = _pad(jnp.swapaxes(g.astype(jnp.float32), 1, 2), 1, negs_p.shape[1])
+    d_m, d_n, d_o = transr_bwd_pallas(o_t, negs_p, m_t, g_t, kc=kc,
+                                      mxu_dtype=_mxu_dtype(), interpret=_interpret())
+    d_o = jnp.swapaxes(jnp.sum(d_o, axis=1)[:, :R], 1, 2)
+    d_m = jnp.transpose(jnp.sum(d_m, axis=1)[:, :R], (0, 3, 2, 1))
+    return d_o.astype(o.dtype), d_n[:, :K].astype(negs.dtype), d_m.astype(m.dtype)
+
+
+transr_l2sq.defvjp(_transr_fwd, _transr_bwd)

@@ -5,6 +5,10 @@ Contract (identical to core/scores.pairwise_scores):
     dot   : o @ negs.T
     l2sq  : ||o_i - n_j||^2        (partial, pre-psum)
     l1    : sum_d |o_id - n_jd|    (partial, pre-psum)
+
+TransR's projected form (ops.transr_l2sq), (G, B, R) x (G, K, D) x
+(G, B, D, R) -> (G, B, K):
+    sum_r (o_b - negs_k @ M_b)_r^2
 """
 
 from __future__ import annotations
@@ -30,3 +34,10 @@ def l1_grads_ref(o, negs, g):
     d_o = jnp.einsum("bk,bkd->bd", g, s)
     d_n = -jnp.einsum("bk,bkd->kd", g, s)
     return d_o, d_n
+
+
+def transr_l2sq_ref(o, negs, m):
+    """Oracle for ops.transr_l2sq: every candidate projected by every
+    triplet's matrix, (G, B, K, R) materialized."""
+    pn = jnp.einsum("gkd,gbdr->gbkr", negs, m)
+    return jnp.sum(jnp.square(o[:, :, None, :] - pn), axis=-1)

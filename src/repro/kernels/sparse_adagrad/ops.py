@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.common.compat import interpret_kernels as _interpret
 from repro.kernels.sparse_adagrad.sparse_adagrad import fused_update_pallas
+from repro.optim.sparse_adagrad import add_rows
 
 
 def _row_tile(D: int) -> int:
@@ -68,9 +69,10 @@ def _group_tiles(ids: jnp.ndarray, grads: jnp.ndarray, tr: int,
     compacted with -1 pads, and row ``r`` of slot ``s`` at ``s*tr + r``
     holding the sum of that row's gradients (mask 1) or nothing (mask 0).
     Every occurrence of a row id lands on the same (slot, row) pair, so the
-    scatter-add is the whole duplicate aggregation. The ids are sorted with
-    pads last, so the destinations never decrease and the scatters are told
-    so (``indices_are_sorted``).
+    scatter-add (``add_rows``, in column blocks for wide rows) is the whole
+    duplicate aggregation. The ids are sorted with pads last, so the
+    destinations never decrease and the scatters are told so
+    (``indices_are_sorted``).
     """
     n, D = grads.shape
     valid = ids >= 0
@@ -82,9 +84,8 @@ def _group_tiles(ids: jnp.ndarray, grads: jnp.ndarray, tr: int,
     tile_ids = jnp.full((n_tiles,), -1, jnp.int32).at[
         jnp.where(first, slot, n_tiles)].set(st, mode="drop")
     dest = jnp.where(sv, slot * tr + sid % tr, n_tiles * tr)
-    grad_tiles = jnp.zeros((n_tiles * tr, D), jnp.float32).at[dest].add(
-        grads[order].astype(jnp.float32), mode="drop",
-        indices_are_sorted=True)
+    grad_tiles = add_rows(n_tiles * tr, dest, grads[order],
+                          indices_are_sorted=True)
     mask = jnp.zeros((n_tiles * tr, 1), jnp.int32).at[dest].set(
         1, mode="drop", indices_are_sorted=True)
     return tile_ids, grad_tiles, mask

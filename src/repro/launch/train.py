@@ -129,7 +129,8 @@ def main(argv=None, hooks=()):
     if args.dim:
         upd["dim"] = args.dim
         # the dataset config already materialized rel_dim from its own dim;
-        # 0 re-derives it from the overridden dim (transr overrides below)
+        # 0 re-derives it from the overridden dim (TransR's rel_dim = dim,
+        # as in DGL-KE)
         upd["rel_dim"] = 0
     if args.batch_size:
         upd["batch_size"] = args.batch_size
@@ -143,8 +144,6 @@ def main(argv=None, hooks=()):
         upd["overlap_update"] = False
     if args.remote_capacity:
         upd["remote_capacity"] = args.remote_capacity
-    if args.model == "transr":
-        upd["rel_dim"] = min(64, cfg.dim)
     upd["partitioner"] = args.partitioner
     cfg = dataclasses.replace(cfg, **upd)
     print(f"graph: {kg.n_entities} entities, {kg.n_relations} relations, "

@@ -80,6 +80,26 @@ def _resolve(flag: Optional[bool]) -> bool:
 # --------------------------------------------------------------------------
 # dedup / aggregation
 # --------------------------------------------------------------------------
+# XLA on TPU lowers a scatter of rows 32,000 floats wide or wider to a
+# serial loop over the updates (one dynamic-update-slice per row, about
+# 11 us each at 40,000 floats on v5e); narrower rows scatter in parallel.
+ROW_CHUNK = 16_384
+
+
+def add_rows(n_rows: int, idx: jnp.ndarray, rows: jnp.ndarray, *,
+             indices_are_sorted: bool = False) -> jnp.ndarray:
+    """``zeros((n_rows, D)).at[idx].add(rows)`` in float32 (ids out of range
+    dropped), as one scatter-add per block of at most ``ROW_CHUNK`` columns,
+    the blocks put side by side: rows of any width scatter in parallel."""
+    d = rows.shape[1]
+    rows = rows.astype(jnp.float32)
+    return jnp.concatenate([
+        jnp.zeros((n_rows, min(ROW_CHUNK, d - c)), jnp.float32).at[idx].add(
+            rows[:, c : c + ROW_CHUNK], mode="drop",
+            indices_are_sorted=indices_are_sorted)
+        for c in range(0, d, ROW_CHUNK)], axis=1)
+
+
 def segment_aggregate_rows(
     ids: jnp.ndarray, grads: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:

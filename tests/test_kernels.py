@@ -7,8 +7,8 @@ import pytest
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
-from repro.kernels.kge_score.ops import pairwise_scores_kernel
-from repro.kernels.kge_score.ref import pairwise_ref
+from repro.kernels.kge_score.ops import pairwise_scores_kernel, transr_l2sq
+from repro.kernels.kge_score.ref import pairwise_ref, transr_l2sq_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_chunked_jnp, ssd_ref
 
@@ -42,6 +42,37 @@ def test_kge_score_grads(mode):
     dor, dnr = jax.grad(fr, argnums=(0, 1))(o, n)
     np.testing.assert_allclose(do, dor, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(dn, dnr, rtol=2e-4, atol=2e-4)
+
+
+# (G, B, K, D, R): widths off the 128 lanes, a B over 128 that pads to 256,
+# a B under 8, and candidates in two blocks of 512 (1,100 pads to 1,536)
+TRANSR_SHAPES = [(2, 16, 24, 20, 12), (1, 5, 7, 9, 11), (2, 136, 8, 8, 16),
+                 (1, 9, 1100, 8, 4), (4, 32, 40, 200, 200)]
+
+
+def _transr_inputs(G, B, K, D, R):
+    rng = np.random.default_rng(G * B * K * D * R)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))  # noqa: E731
+    return f(G, B, R), f(G, K, D), 0.3 * f(G, B, D, R), f(G, B, K)
+
+
+@pytest.mark.parametrize("shape", TRANSR_SHAPES)
+def test_transr_score_matches_ref(shape):
+    o, n, m, _ = _transr_inputs(*shape)
+    np.testing.assert_allclose(transr_l2sq(o, n, m), transr_l2sq_ref(o, n, m),
+                               rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("shape", TRANSR_SHAPES)
+def test_transr_score_grads_match_ref(shape):
+    """The backward kernel's d_o, d_negs and d_m against autodiff of the
+    oracle."""
+    o, n, m, g = _transr_inputs(*shape)
+    got = jax.grad(lambda *a: jnp.sum(transr_l2sq(*a) * g), argnums=(0, 1, 2))(o, n, m)
+    want = jax.grad(lambda *a: jnp.sum(transr_l2sq_ref(*a) * g), argnums=(0, 1, 2))(o, n, m)
+    for name, x, y in zip(("d_o", "d_negs", "d_m"), got, want):
+        scale = float(jnp.max(jnp.abs(y)))
+        np.testing.assert_allclose(x, y, rtol=2e-5, atol=2e-5 * scale, err_msg=name)
 
 
 # ------------------------------------------------------------- flash attention

@@ -62,6 +62,43 @@ def test_kernel_path_matches_jnp(small_kg, model):
     np.testing.assert_allclose(s_k.entity, s_ref.entity, rtol=2e-3, atol=2e-4)
 
 
+def test_transr_kernel_path_matches_einsum(small_kg, monkeypatch):
+    """With the platform read as a TPU, TransR's joint negatives go through
+    the kge.transr_score kernels (interpret mode here), each mode's groups
+    on the kernel's grid, and train as the einsum path does."""
+    import types
+
+    from repro.common import compat
+    from repro.core import scores
+
+    cfg = _cfg(small_kg, "transr", neg_group_size=32)
+    sampler = JointSampler(small_kg.train, cfg.n_entities, cfg,
+                           np.random.default_rng(0))
+    batches = [batch_to_device(sampler.sample()) for _ in range(4)]
+
+    def run():
+        state = init_state(cfg, jax.random.key(0), overlap=True)
+        step = make_train_step(cfg)
+        losses = []
+        for b in batches:
+            state, m = step(state, b)
+            losses.append(float(m["loss"]))
+        return np.asarray(losses), state, step
+
+    l_ref, s_ref, step_ref = run()
+    monkeypatch.setattr(scores, "compat", types.SimpleNamespace(
+        backend=lambda: "tpu", axis_size=compat.axis_size))
+    l_k, s_k, step_k = run()
+    batch = batches[0]
+    state = init_state(cfg, jax.random.key(0), overlap=True)
+    assert "transr_score" in str(jax.make_jaxpr(step_k)(state, batch))
+    assert "transr_score" not in str(jax.make_jaxpr(step_ref)(state, batch))
+    np.testing.assert_allclose(l_k, l_ref, rtol=1e-5)
+    for tab in ("entity", "r_emb", "r_proj"):
+        a, b = np.asarray(getattr(s_k, tab)), np.asarray(getattr(s_ref, tab))
+        assert np.linalg.norm(a - b) <= 1e-4 * np.linalg.norm(b), tab
+
+
 def test_naive_baseline_also_learns(small_kg):
     cfg = _cfg(small_kg, "transe_l2")
     state = init_state(cfg, jax.random.key(0))
@@ -161,3 +198,18 @@ def test_self_adversarial_loss(small_kg):
     neg_hard = jnp.asarray([[5.0, -10.0]])
     assert float(self_adversarial_loss(pos, neg_hard)) > float(
         self_adversarial_loss(pos, neg_easy))
+
+
+def test_launcher_builds_transr_at_the_given_dim(monkeypatch):
+    """``--model transr --dim 200`` trains rel_dim 200 (DGL-KE's
+    relation_dim = hidden_dim): 200-wide relations, 200x200 projections."""
+    from repro.common import compile_cache
+    from repro.launch.train import main
+
+    monkeypatch.setattr(compile_cache, "use_compile_cache", lambda: "")
+    state = main(["--model", "transr", "--dim", "200", "--steps", "2",
+                  "--scale", "0.01", "--batch-size", "16", "--neg", "8",
+                  "--log-every", "1"])
+    assert state.entity.shape[1] == 200
+    assert state.r_emb.shape[1] == 200
+    assert state.r_proj.shape[1] == 200 * 200

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.optim.dense import adafactor, adamw, sgd
@@ -98,3 +99,19 @@ def test_adafactor_state_is_factored():
     assert state["stats"]["w"]["vr"].shape == (64,)
     assert state["stats"]["w"]["vc"].shape == (32,)
     assert state["stats"]["b"]["v"].shape == (32,)
+
+
+@pytest.mark.parametrize("width", [5, 16, 40])
+def test_add_rows_matches_one_scatter(width, monkeypatch):
+    """add_rows in column blocks (here of 16) sums duplicate and dropped ids
+    as one scatter-add of the whole rows does."""
+    from repro.optim import sparse_adagrad as SA
+
+    monkeypatch.setattr(SA, "ROW_CHUNK", 16)
+    rng = np.random.default_rng(width)
+    # repeats, and ids 8 and 99 out of range (dropped)
+    idx = jnp.asarray(rng.integers(-1, 9, 30), jnp.int32)
+    idx = jnp.where(idx < 0, 99, idx)
+    rows = jnp.asarray(rng.standard_normal((30, width)), jnp.float32)
+    want = jnp.zeros((8, width), jnp.float32).at[idx].add(rows, mode="drop")
+    np.testing.assert_allclose(SA.add_rows(8, idx, rows), want, rtol=1e-6, atol=1e-6)
