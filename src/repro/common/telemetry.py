@@ -76,6 +76,10 @@ KNOWN_METRICS: Dict[str, str] = {
                                 "full queue (consumer is the bottleneck)",
     "pipeline/consumer_wait_s": "counter(seconds): consumers blocked on an "
                                 "empty queue (sampling is the bottleneck)",
+    "pipeline/to_device_transfers": "counter: host-to-device transfers made "
+                                    "by kge_model.batch_to_device (one per "
+                                    "batch)",
+    "pipeline/to_device_bytes": "counter: bytes those transfers carried",
     "pipeline/queue_depth": "gauge: bounded batch-queue depth at last update",
     # embedding stores (trace-time for jitted steps; see module docstring)
     "store/flush_calls": "counter: non-empty pend-buffer flushes (per trace "

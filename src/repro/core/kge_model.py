@@ -15,14 +15,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+import math
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.common import telemetry
 from repro.common.config import KGEConfig
-from repro.core.sampling import MODES, KGBatch
+from repro.core.sampling import MODES, KGBatch, NaiveBatch
 from repro.core.step import store_apply_grads, store_grads, store_train_step
 from repro.embeddings.store import DenseStore
 from repro.embeddings.table import emb_init_scale
@@ -121,16 +123,45 @@ def state_from_stores(state: KGEState, stores: Dict[str, DenseStore]) -> KGEStat
     )
 
 
-def dense_step_batch(batch: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
-    """Lower a global-id batch (h, r, t, neg) to the step's workspace form."""
-    h, r, t, neg = batch["h"], batch["r"], batch["t"], batch["neg"]
-    b = h.shape[0]
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["ids"], meta_fields=["b", "neg_shape"])
+@dataclasses.dataclass(frozen=True)
+class PackedBatch:
+    """A batch as one int32 device array ``[h | t | neg.reshape(-1) | r]``.
+
+    ``ids`` is the only leaf; ``b`` and ``neg_shape`` are static, so a jitted
+    step compiles once per batch shape, as with the dict form.
+    """
+
+    ids: jnp.ndarray  # (3b + prod(neg_shape),) int32
+    b: int
+    neg_shape: Tuple[int, ...]
+
+
+def dense_step_batch(
+    batch: Union[PackedBatch, Dict[str, jnp.ndarray]],
+) -> Dict[str, jnp.ndarray]:
+    """Lower a global-id batch to the step's workspace form.
+
+    ``batch`` is a ``PackedBatch`` (``batch_to_device``) or a dict of h, r,
+    t (b,) and neg; both give the same ids in the same slots.
+    """
+    if isinstance(batch, PackedBatch):
+        b, neg_shape = batch.b, batch.neg_shape
+        n_ent = 2 * b + math.prod(neg_shape)
+        ent_ids, rel_ids = batch.ids[:n_ent], batch.ids[n_ent:]
+    else:
+        h, r, t, neg = batch["h"], batch["r"], batch["t"], batch["neg"]
+        b, neg_shape = h.shape[0], neg.shape
+        ent_ids = jnp.concatenate([h, t, neg.reshape(-1)]).astype(jnp.int32)
+        rel_ids = r.astype(jnp.int32)
+    n_neg = math.prod(neg_shape)
     return {
-        "ent_ids": jnp.concatenate([h, t, neg.reshape(-1)]).astype(jnp.int32),
-        "rel_ids": r.astype(jnp.int32),
+        "ent_ids": ent_ids,
+        "rel_ids": rel_ids,
         "h_slot": jnp.arange(b, dtype=jnp.int32),
         "t_slot": b + jnp.arange(b, dtype=jnp.int32),
-        "neg_slot": 2 * b + jnp.arange(neg.size, dtype=jnp.int32).reshape(neg.shape),
+        "neg_slot": 2 * b + jnp.arange(n_neg, dtype=jnp.int32).reshape(neg_shape),
         "rel_slot": jnp.arange(b, dtype=jnp.int32),
     }
 
@@ -149,12 +180,13 @@ def flush_state(cfg: KGEConfig, state: KGEState) -> KGEState:
 def train_step(
     cfg: KGEConfig,
     state: KGEState,
-    batch: Dict[str, jnp.ndarray],
+    batch: Union[PackedBatch, Dict[str, jnp.ndarray]],
     pairwise_fn=None,
 ) -> Tuple[KGEState, Dict[str, jnp.ndarray]]:
     """One sparse mini-batch step (jit-able; batch arrays are device arrays).
 
-    batch: h, r, t (b,), neg (MODES, ng, k).
+    batch: a ``PackedBatch``, or a dict of h, r, t (b,) and neg
+    (MODES, ng, k) (see ``dense_step_batch``).
     """
     stores, metrics = store_train_step(
         cfg, stores_from_state(cfg, state), dense_step_batch(batch),
@@ -211,15 +243,18 @@ def make_hogwild_step(cfg: KGEConfig, pairwise_fn=None):
     return g, a
 
 
-def batch_to_device(batch: KGBatch) -> Dict[str, jnp.ndarray]:
-    """The host-to-device copy of one batch (span ``pipeline/to_device``)."""
+def batch_to_device(batch: Union[KGBatch, NaiveBatch]) -> PackedBatch:
+    """The host-to-device copy of one batch (span ``pipeline/to_device``):
+    one int32 host array, one ``device_put``. A transfer pays a fixed cost
+    per call, which a batch of a few thousand ids would pay once per field."""
     with telemetry.span("pipeline/to_device"):
-        return {
-            "h": jnp.asarray(batch.h, jnp.int32),
-            "r": jnp.asarray(batch.r, jnp.int32),
-            "t": jnp.asarray(batch.t, jnp.int32),
-            "neg": jnp.asarray(batch.neg, jnp.int32),
-        }
+        ids = np.concatenate([batch.h, batch.t, batch.neg.reshape(-1), batch.r],
+                             dtype=np.int32)
+        out = PackedBatch(jax.device_put(ids), batch.h.shape[0],
+                          tuple(batch.neg.shape))
+    telemetry.inc("pipeline/to_device_transfers")
+    telemetry.inc("pipeline/to_device_bytes", ids.nbytes)
+    return out
 
 
 # --------------------------------------------------------------------------

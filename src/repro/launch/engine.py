@@ -34,6 +34,7 @@ mutable state (t0, histories, last-saved markers) without their own locks;
 
 from __future__ import annotations
 
+import collections
 import json
 import time
 import warnings
@@ -334,6 +335,13 @@ def _finish(i: int, state, hooks):
     return state
 
 
+# Steps the single-trainer loop lets run ahead of the device: enough to keep
+# it fed while the host prepares the next batch. Each step in flight holds
+# its outputs on the device, so a loop that dispatched up to its next sync
+# (a hook reading the loss every 100 steps) could fill the device's memory.
+_IN_FLIGHT = 2
+
+
 def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
                hooks: Sequence[Hook] = (), prefetch: bool = True,
                n_trainers: int = 1, n_samplers: int = 1,
@@ -341,7 +349,9 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
     """Drive ``step_fn`` from ``start`` (exclusive) to ``n_steps``.
 
     make_batch() -> (batch, stats); stats may be None. With ``prefetch``
-    batches are produced one step ahead on a host thread.
+    batches are produced one step ahead on a host thread. After dispatching
+    step i the loop waits for the metrics of step i - ``_IN_FLIGHT``
+    (span ``engine/step_wait``), so dispatch never runs further ahead.
 
     ``n_trainers``/``n_samplers`` > 1 switch to the Hogwild multi-trainer
     runtime (launch/runtime.py): ``sampler_factory(worker_id)`` builds one
@@ -377,7 +387,17 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
         raise ValueError(
             "pipelined lookahead step requires prefetch=True: the one-batch "
             "lookahead is WorkerPool.peek() on the prefetch queue")
+    import jax
+
     src = Prefetcher(make_batch) if prefetch else iter(make_batch, object())
+    in_flight = collections.deque()
+
+    def bound_in_flight(metrics):
+        in_flight.append(metrics)
+        if len(in_flight) > _IN_FLIGHT:
+            with telemetry.span("engine/step_wait"):
+                jax.block_until_ready(in_flight.popleft())
+
     i = start
     try:
         if lookahead:
@@ -386,6 +406,7 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
                 nxt, _ = src.peek()
                 with telemetry.span("engine/step"):
                     state, metrics = step_fn(state, batch, nxt)
+                bound_in_flight(metrics)
                 with telemetry.span("engine/hooks"):
                     for h in hooks:
                         h.on_step(i, state, metrics, stats)
@@ -393,6 +414,7 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
             for i, (batch, stats) in zip(range(start + 1, n_steps + 1), src):
                 with telemetry.span("engine/step"):
                     state, metrics = step_fn(state, batch)
+                bound_in_flight(metrics)
                 with telemetry.span("engine/hooks"):
                     for h in hooks:
                         h.on_step(i, state, metrics, stats)

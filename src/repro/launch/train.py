@@ -168,7 +168,6 @@ def _train_single(args, cfg, kg, pairwise_fn, extra_hooks):
     import functools
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.common.checkpoint import latest_step, restore_checkpoint
@@ -201,22 +200,18 @@ def _train_single(args, cfg, kg, pairwise_fn, extra_hooks):
         step = make_train_step(cfg, pairwise_fn)
         if hogwild:  # stale-gradient two-phase step (paper §3.1)
             split_step = make_hogwild_step(cfg, pairwise_fn)
-        to_dev = batch_to_device
     else:
         def make_sampler(r):
             return NaiveSampler(kg.train, cfg.n_entities, cfg, r)
 
         step = jax.jit(functools.partial(naive_train_step, cfg))
-        to_dev = lambda b: {
-            "h": jnp.asarray(b.h, jnp.int32), "r": jnp.asarray(b.r, jnp.int32),
-            "t": jnp.asarray(b.t, jnp.int32), "neg": jnp.asarray(b.neg, jnp.int32)}
     sampler = make_sampler(rng)
     samplers = [make_sampler(r)
                 for r in worker_rngs(args.seed, max(1, args.samplers))]
 
     def sampler_factory(wid):
         s = samplers[wid]
-        return lambda: (to_dev(s.sample()), None)
+        return lambda: (batch_to_device(s.sample()), None)
 
     start = 0
     if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
@@ -250,7 +245,7 @@ def _train_single(args, cfg, kg, pairwise_fn, extra_hooks):
         hooks.append(EvalHook(evaluate, eval_every=args.eval_every))
     hooks.extend(extra_hooks)
 
-    return train_loop(step, state, lambda: (to_dev(sampler.sample()), None),
+    return train_loop(step, state, lambda: (batch_to_device(sampler.sample()), None),
                       args.steps, start=start, hooks=hooks,
                       n_trainers=args.trainers, n_samplers=args.samplers,
                       sampler_factory=sampler_factory, split_step=split_step)

@@ -118,6 +118,36 @@ def test_train_loop_prefetches():
     assert state == 6
 
 
+def test_train_loop_bounds_steps_in_flight():
+    """After dispatching step i the loop waits for step i - 2 to finish, so
+    no more than two steps run ahead of the device, however far away the
+    next hook that syncs is."""
+    from repro.launch import engine
+
+    events = []
+
+    class Done:
+        def __init__(self, i):
+            self.i = i
+
+        def block_until_ready(self):
+            events.append(("wait", self.i))
+            return self
+
+    def step(state, batch):
+        events.append(("step", state + 1))
+        return state + 1, {"loss": Done(state + 1)}
+
+    assert train_loop(step, 0, _batches, 6, prefetch=False) == 6
+    k = engine._IN_FLIGHT
+    want = []
+    for i in range(1, 7):
+        want.append(("step", i))
+        if i > k:
+            want.append(("wait", i - k))
+    assert events == want
+
+
 def test_eval_hook_periodic_and_final():
     evals = []
     hook = EvalHook(lambda state: evals.append(state), eval_every=2)

@@ -174,6 +174,75 @@ def test_overlap_single_machine(small_kg):
                                np.asarray(s1_im.entity), rtol=1e-6, atol=1e-7)
 
 
+def _dict_batch(b):
+    """The four-field device form of a sampled batch (the dict
+    ``dense_step_batch`` also takes)."""
+    import jax.numpy as jnp
+
+    return {k: jnp.asarray(getattr(b, k), jnp.int32) for k in ("h", "r", "t", "neg")}
+
+
+@pytest.mark.parametrize("path,overlap", [("train", False), ("train", True),
+                                          ("hogwild", False), ("naive", False)])
+def test_packed_batch_trains_bitwise_as_dict(small_kg, path, overlap):
+    """The one-transfer ``PackedBatch`` and the dict of the same batches give
+    the same loss and state, bit for bit, after three steps, through the
+    one-shot step (T5 on and off), Hogwild's grad and apply pair and the
+    naive-negative step."""
+    import functools
+
+    from repro.core.kge_model import PackedBatch, make_hogwild_step
+
+    cfg = _cfg(small_kg, "transe_l2")
+    sampler = (NaiveSampler if path == "naive" else JointSampler)(
+        small_kg.train, cfg.n_entities, cfg, np.random.default_rng(0))
+    sampled = [sampler.sample() for _ in range(3)]
+
+    def run(to_dev):
+        state = init_state(cfg, jax.random.key(0), overlap=overlap)
+        if path == "train":
+            step = make_train_step(cfg)
+        elif path == "naive":
+            step = jax.jit(functools.partial(naive_train_step, cfg))
+        else:
+            g, a = make_hogwild_step(cfg)
+
+            def step(st, batch):
+                grads, m = g(st, batch)
+                return a(st, batch, grads), m
+        losses = []
+        for b in sampled:
+            state, m = step(state, to_dev(b))
+            losses.append(np.asarray(m["loss"]))
+        return losses, state
+
+    packed = batch_to_device(sampled[0])
+    assert isinstance(packed, PackedBatch) and len(jax.tree.leaves(packed)) == 1
+    l_p, s_p = run(batch_to_device)
+    l_d, s_d = run(_dict_batch)
+    np.testing.assert_array_equal(np.stack(l_p), np.stack(l_d))
+    for a, b in zip(jax.tree.leaves(s_p), jax.tree.leaves(s_d), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_dense_step_batch_same_for_packed_and_dict(small_kg):
+    """Both batch forms lower to the same workspace ids and slots."""
+    from repro.core.kge_model import dense_step_batch
+
+    cfg = _cfg(small_kg, "transe_l2")
+    b = JointSampler(small_kg.train, cfg.n_entities, cfg,
+                     np.random.default_rng(0)).sample()
+    packed = dense_step_batch(batch_to_device(b))
+    plain = dense_step_batch(_dict_batch(b))
+    assert packed.keys() == plain.keys()
+    for k in plain:
+        assert packed[k].dtype == plain[k].dtype, k
+        np.testing.assert_array_equal(np.asarray(packed[k]), np.asarray(plain[k]))
+    np.testing.assert_array_equal(
+        np.asarray(packed["ent_ids"]),
+        np.concatenate([b.h, b.t, b.neg.reshape(-1)]))
+
+
 def test_self_adversarial_loss(small_kg):
     """RotatE with self-adversarial negative weighting (the RotatE-codebase
     option DGL-KE inherits) trains stably and weights hard negatives."""

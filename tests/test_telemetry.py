@@ -327,6 +327,29 @@ def _tiny_kge(**kw):
     return KGEConfig(**base)
 
 
+def test_batch_to_device_counts_one_transfer():
+    """One ``batch_to_device`` call is one host-to-device transfer of
+    ``4 * (3b + 2 * ng * k)`` bytes."""
+    import numpy as np
+
+    from repro.core.kge_model import batch_to_device
+    from repro.core.sampling import JointSampler
+
+    cfg = _tiny_kge()
+    rng = np.random.default_rng(0)
+    trip = np.stack([rng.integers(0, cfg.n_entities, 200),
+                     rng.integers(0, cfg.n_relations, 200),
+                     rng.integers(0, cfg.n_entities, 200)], 1)
+    batch = JointSampler(trip, cfg.n_entities, cfg, rng).sample()
+    b, ng, k = cfg.batch_size, cfg.n_neg_groups, cfg.neg_sample_size
+    with telemetry.active() as reg:
+        batch_to_device(batch)
+        assert reg.counters["pipeline/to_device_transfers"] == 1
+        assert reg.counters["pipeline/to_device_bytes"] == 4 * (3 * b + 2 * ng * k)
+        batch_to_device(batch)
+        assert reg.counters["pipeline/to_device_transfers"] == 2
+
+
 def _train_path(cfg, n_steps, step_fn=None, delay=0.0):
     """``train_loop`` fed the way the trainer feeds it: the sampler and the
     host-to-device copy in the producer thread."""
