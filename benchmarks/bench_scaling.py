@@ -1,8 +1,8 @@
 """Paper Figs. 5/6: scaling with compute units.
 
 CPU-SPMD throughput scaling over 1/2/4/8-way data parallelism (same global
-batch per unit, like the paper's per-GPU batch), plus the dry-run roofline
-scaling story is in benchmarks/bench_roofline.py."""
+batch per unit, like the paper's per-GPU batch); the pod-scale compile
+rehearsal of the distributed step is ``python -m repro.launch.dryrun --kge``."""
 
 from __future__ import annotations
 

@@ -14,7 +14,7 @@ as ``--metrics-out`` lines, docs/TELEMETRY.md) holding every emitted row as
 a ``bench/<name>`` gauge. Set BENCH_FAST=0 (or --full) for paper-scale
 accuracy runs.
 
-Mapping (see DESIGN.md §6):
+Mapping:
     fig3    bench_negative_sampling   joint vs naive sampling (T1)
     table4  bench_degree_negatives    degree-based negatives (T2)
     fig4    bench_overlap             overlap update + relation partitioning
@@ -23,7 +23,6 @@ Mapping (see DESIGN.md §6):
     table5  bench_accuracy            per-model accuracy tables
     kernel  bench_kernels             T1 GEMM arithmetic intensity
     sparse_adagrad bench_kernels      fused Adagrad kernel HBM traffic
-    roofline bench_roofline           dry-run roofline table (pod scale)
     hogwild bench_hogwild             §3.1 multi-trainer triplets/s scaling
     pipeline bench_pipeline           pipelined pull prefetch + coalesced push
 """
@@ -53,7 +52,7 @@ def main() -> None:
     from benchmarks import (
         bench_accuracy, bench_capacity, bench_degree_negatives, bench_hogwild,
         bench_kernels, bench_negative_sampling, bench_overlap,
-        bench_partitioning, bench_pipeline, bench_roofline, bench_scaling,
+        bench_partitioning, bench_pipeline, bench_scaling,
     )
 
     suites = {
@@ -66,7 +65,6 @@ def main() -> None:
         "table5": bench_accuracy.run,
         "kernel": bench_kernels.run,
         "sparse_adagrad": bench_kernels.run_sparse_adagrad,
-        "roofline": bench_roofline.run,
         "hogwild": bench_hogwild.run,
         "pipeline": bench_pipeline.run,
     }

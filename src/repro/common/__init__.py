@@ -1,23 +1,9 @@
-"""Shared substrate: configs, hardware constants, sharding/tree helpers."""
+"""Shared substrate: the KGE config and hardware constants."""
 
 from repro.common.hw import TPU_V5E
-from repro.common.config import (
-    ArchConfig,
-    AttentionKind,
-    FFNKind,
-    InputShape,
-    KGEConfig,
-    MixerKind,
-    INPUT_SHAPES,
-)
+from repro.common.config import KGEConfig
 
 __all__ = [
     "TPU_V5E",
-    "ArchConfig",
-    "AttentionKind",
-    "FFNKind",
-    "InputShape",
     "KGEConfig",
-    "MixerKind",
-    "INPUT_SHAPES",
 ]

@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
-Called once by each entry point (launch/train.py, launch/serve.py,
-benchmarks/run.py, chip_smoke.py), never at import time:
+Called once by each entry point (launch/train.py, benchmarks/run.py,
+benchmarks/chip/run.py, chip_smoke.py), never at import time:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this module
   sets nothing, so the cache lands only where the environment says.

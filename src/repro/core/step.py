@@ -107,9 +107,9 @@ def store_grads(
 
     b = h_slot.shape[0]
     k = cfg.neg_sample_size
-    ng = cfg.n_neg_groups
-    # negative-sharding (EXPERIMENTS.md §Perf hillclimb 3): local (b, k/S)
-    # score slices + scalar loss psum, instead of psum-ing (b, k) scores.
+    ng = neg_slot.shape[1]  # (MODES, ng, k) joint, (MODES, b, k) naive
+    # negative-sharding: local (b, k/S) score slices + scalar loss psum,
+    # instead of psum-ing (b, k) scores.
     sharded_negs = (
         neg_mode == "joint"
         and ctx.axis is not None
@@ -127,28 +127,8 @@ def store_grads(
         pos = S.positive_score(cfg.model, h, r, t, cfg.gamma, ctx,
                                r_proj=pr, rel_dim=cfg.rel_dim, emb_scale=scale)
 
-        if neg_mode == "naive":
-            # independent negatives per triplet — the paper's O(b·k·d) strawman
-            outs = []
-            for m in range(MODES):
-                corrupt = "tail" if m == 0 else "head"
-                e = h if m == 0 else t
-                o = S.neg_o(cfg.model, e, r, corrupt, ctx, emb_scale=scale)
-                negs = ws_[neg_slot[m]]  # (b, k, d)
-                mode = S.PAIRWISE_OF[cfg.model]
-                if mode == "dot":
-                    part = jnp.einsum("bd,bkd->bk", o, negs)
-                elif mode == "l2sq":
-                    part = jnp.sum(jnp.square(o[:, None, :] - negs), axis=-1)
-                else:
-                    part = jnp.sum(jnp.abs(o[:, None, :] - negs), axis=-1)
-                outs.append(S.finish_neg_scores(cfg.model, part, cfg.gamma, ctx))
-            neg = jnp.stack(outs)  # (MODES, b, k)
-            loss = L.kge_loss(cfg.loss, jnp.concatenate([pos, pos]),
-                              neg.reshape(MODES * b, -1), margin=cfg.gamma)
-            return loss, (jnp.mean(pos), jnp.mean(neg))
-
-        # joint negatives (T1): one pool of k entities per group of gsz triplets
+        # joint negatives (T1): one pool of k entities per group of gsz
+        # triplets; naive negatives are the same with one triplet a group
         gsz = b // ng
         neg_out = []
         for m in range(MODES):

@@ -1,4 +1,4 @@
-"""One step-loop for every driver — train, serve, examples, benchmarks.
+"""One step-loop for every driver — train, examples, benchmarks.
 
 The paper's trainers differ only in how a batch is made and how state is
 stepped; the loop around them (prefetch, logging, checkpointing, eval) is
@@ -10,15 +10,15 @@ identical. This module is that loop, with behavior injected as hooks:
 ``make_batch() -> (batch, stats)`` runs in the Prefetcher's producer thread
 (overlapping host-side sampling with device compute, paper T5's cheap half);
 ``step_fn(state, batch) -> (state, metrics)`` is any jitted step —
-single-machine ``train_step``, the shard_map distributed step, or a decode
-step via ``run_loop``.
+single-machine ``train_step`` or the shard_map distributed step;
+``run_loop`` drives a step indexed by step number alone.
 
 Hooks see every step *after* it is issued: ``on_step(i, state, metrics,
 stats)`` with ``i`` the 1-based step number, then ``on_end(i, state)`` once.
 ``TelemetryHook`` is the observability surface: it folds step metrics,
 sampler stats, and trace-time comm statics into the process metrics
 registry (common/telemetry.py) and emits JSONL snapshots / Chrome traces
-(``--metrics-out`` / ``--trace-out`` in train.py and serve.py).
+(``--metrics-out`` / ``--trace-out`` in train.py).
 ``on_end`` may return a replacement state (e.g. a flushed one); ``None``
 keeps the current state.
 
@@ -196,7 +196,7 @@ class MetricsHook(Hook):
 
 
 class ThroughputHook(Hook):
-    """One end-of-run throughput line (serve / benchmark loops).
+    """One end-of-run throughput line (training / benchmark loops).
 
     The clock starts at the *first step* (like ``LoggingHook``), so jit
     compile / setup time between construction and the loop no longer
@@ -430,7 +430,7 @@ def train_loop(step_fn, state, make_batch, n_steps: int, *, start: int = 0,
 def run_loop(step_fn, state, n_steps: int, *, start: int = 0,
              hooks: Sequence[Hook] = ()):
     """Batch-free variant: ``step_fn(i, state) -> (state, metrics)`` with the
-    0-based step index — serve decode loops, synthetic benchmark loops."""
+    0-based step index — synthetic benchmark loops."""
     i = start
     for i in range(start + 1, n_steps + 1):
         with telemetry.span("engine/step"):

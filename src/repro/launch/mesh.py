@@ -3,9 +3,10 @@
 Single pod: (16, 16) = ('data', 'model')  — 256 chips (TPU v5e pod).
 Multi-pod:  (2, 16, 16) = ('pod', 'data', 'model') — 512 chips.
 
-A FUNCTION, not a module constant: importing this module must never touch
-jax device state (smoke tests run with 1 CPU device; only launch/dryrun.py
-sets xla_force_host_platform_device_count).
+The pod meshes are the targets of ``launch/dryrun.py --kge``. A FUNCTION,
+not a module constant: importing this module must never touch jax device
+state (tests force 8 CPU devices in tests/conftest.py; only launch/dryrun.py
+sets the 512-device count).
 
 Mesh construction is version-sensitive (axis-type keywords came and went);
 all of it goes through repro.common.compat.

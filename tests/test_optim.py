@@ -1,6 +1,5 @@
 """Sparse Adagrad (DGL-KE's optimizer) + dense optimizers."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,7 +7,7 @@ from _hypothesis_compat import given, settings, strategies as st
 
 from repro.optim.dense import adafactor, adamw, sgd
 from repro.optim.sparse_adagrad import (
-    AdagradState, dense_adagrad_update, segment_aggregate_rows,
+    dense_adagrad_update, segment_aggregate_rows,
     sparse_adagrad_init, sparse_adagrad_update_rows,
 )
 

@@ -4,7 +4,7 @@ import numpy as np
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.graph_part import (
-    cut_fraction, make_partition_book, metis_like_partition, partition,
+    cut_fraction, metis_like_partition, partition,
     random_partition,
 )
 from repro.core.rel_part import load_imbalance, relation_partition

@@ -1,58 +1,15 @@
-"""Beyond-paper §Perf features: dp/ZeRO-3 mode, chunked CE, negative-sharded
-KGE scoring — each must be numerically equivalent to its baseline path."""
-
-import dataclasses
+"""Negative-sharded KGE scoring must equal the psum-reduced baseline path."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_arch
 from repro.core import scores as S
-from repro.models.layers import chunked_cross_entropy, cross_entropy_logits
-from repro.models.transformer import build_model
 from repro.common.compat import set_mesh, shard_map
 
 RNG = np.random.default_rng(0)
-
-
-def test_chunked_ce_equals_full():
-    B, T, D, V = 4, 8, 16, 64
-    x = jnp.asarray(RNG.standard_normal((B, T, D)).astype(np.float32))
-    w = jnp.asarray(RNG.standard_normal((D, V)).astype(np.float32))
-    labels = jnp.asarray(RNG.integers(0, V, (B, T)), jnp.int32)
-    full = cross_entropy_logits(x @ w, labels, V)
-    for chunk in (8, 16, 64):
-        ck = chunked_cross_entropy(x, w, labels, chunk)
-        np.testing.assert_allclose(ck, full, rtol=1e-5, atol=1e-5)
-    # gradients agree too
-    gf = jax.grad(lambda xx: cross_entropy_logits(xx @ w, labels, V))(x)
-    gc = jax.grad(lambda xx: chunked_cross_entropy(xx, w, labels, 16))(x)
-    np.testing.assert_allclose(gc, gf, rtol=1e-4, atol=1e-5)
-
-
-def test_dp_mode_loss_equals_tp(mesh8):
-    base = dataclasses.replace(get_arch("qwen1.5-0.5b").reduced(), n_layers=2,
-                               vocab_size=1024, dtype="float32")
-    B, T = 8, 16
-    tokens = jnp.asarray(RNG.integers(0, 1024, (B, T)), jnp.int32)
-    inputs = {"tokens": tokens, "labels": tokens}
-    losses, params0 = {}, None
-    for mode, ck in [("tp", 0), ("dp", 0), ("dp", 256)]:
-        cfg = dataclasses.replace(base, parallel=mode, ce_chunk=ck)
-        m = build_model(cfg, mesh=mesh8)
-        if params0 is None:
-            params0 = m.init(jax.random.key(0))
-        with set_mesh(mesh8):
-            p = jax.device_put(params0, jax.tree.map(
-                lambda s: NamedSharding(mesh8, s), m.param_specs(),
-                is_leaf=lambda x: isinstance(x, P)))
-            losses[(mode, ck)] = float(jax.jit(m.loss)(p, inputs))
-    ref = losses[("tp", 0)]
-    for k, v in losses.items():
-        assert abs(v - ref) < 1e-3, (k, v, ref)
 
 
 @pytest.mark.parametrize("model", ["transe_l2", "transe_l1", "distmult",
@@ -81,14 +38,3 @@ def test_negative_sharded_equals_psum(mesh8, model):
     # matching the all_to_all(split k) distribution order
     np.testing.assert_allclose(got, ref, rtol=3e-4, atol=3e-4)
 
-
-def test_param_dtype_bf16_smoke():
-    cfg = dataclasses.replace(get_arch("mamba2-2.7b").reduced(),
-                              param_dtype="bfloat16")
-    m = build_model(cfg)
-    params = m.init(jax.random.key(0))
-    assert params["layers"]["l0"]["mamba"]["w_xz"].dtype == jnp.bfloat16
-    assert params["final_ln"].dtype == jnp.float32  # norms stay fp32
-    tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
-    loss = m.loss(params, {"tokens": tokens, "labels": tokens})
-    assert np.isfinite(float(loss))
